@@ -19,10 +19,12 @@ benchmarks/results/BENCH_distributed.json with an appended history entry:
     per-point against the single-process run: the GSPMD-sharded
     executable must reproduce the single-host numbers.
   * cold vs cache-warm first dispatch — a fresh process compiles
-    `simulate` + `sweep_topology` into an empty persistent cache (cold
-    wall), then a second fresh process repeats the identical calls
-    against the now-populated cache (warm wall). The acceptance bar is
-    warm <= 25% of cold on both entry points.
+    `simulate` + `sweep_topology` with the persistent cache off (cold
+    wall); after another process has filled the persistent cache, a
+    fresh process repeats the identical calls against it (warm wall).
+    The acceptance bar is warm <= 25% of cold on both entry points.
+
+Every child runs with JAX_PLATFORMS=cpu: these are CPU host timings.
 """
 from __future__ import annotations
 
@@ -51,11 +53,15 @@ PARITY = ["--chiplets", "4,9", "--placements", "2",
           "--seed", "0", "--dump-points"]
 
 
-def _fleet(extra, out_path, cache_dir, timeout=900) -> dict:
-    env = dict(os.environ, PYTHONPATH=f"{REPO}/src")
+# Every child runs on the CPU backend: these measurements rehearse a
+# multi-host fleet on one machine, and an accelerator serves one process.
+_CHILD_ENV = dict(os.environ, PYTHONPATH=f"{REPO}/src", JAX_PLATFORMS="cpu")
+
+
+def _fleet(extra, out_path, timeout=900) -> dict:
     cmd = [sys.executable, "-m", "repro.launch.fleet",
-           "--cache-dir", str(cache_dir), "--out", str(out_path)] + extra
-    proc = subprocess.run(cmd, cwd=REPO, env=env, timeout=timeout,
+           "--out", str(out_path)] + extra
+    proc = subprocess.run(cmd, cwd=REPO, env=_CHILD_ENV, timeout=timeout,
                           capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(f"fleet run failed ({cmd}):\n{proc.stdout}\n"
@@ -63,7 +69,7 @@ def _fleet(extra, out_path, cache_dir, timeout=900) -> dict:
     return json.loads(Path(out_path).read_text())
 
 
-def emulated_scaling(cache_dir, tmp) -> dict:
+def emulated_scaling(tmp) -> dict:
     """Warm sweep walls for the 1-worker and 2-worker shardings of the
     same grid; aggregate points/sec = K / max(worker walls)."""
     out = {"mode": "emulated-hosts", "grid_points": SCALING_K,
@@ -75,7 +81,7 @@ def emulated_scaling(cache_dir, tmp) -> dict:
         shards = []
         for i in range(n):
             j = _fleet(SCALING + ["--shard", f"{i}:{n}"],
-                       tmp / f"scale_{n}_{i}.json", cache_dir)
+                       tmp / f"scale_{n}_{i}.json")
             shards.append({"shard": f"{i}:{n}",
                            "grid_points": j["grid_points"],
                            "first_call_s": j["first_call_s"],
@@ -92,12 +98,12 @@ def emulated_scaling(cache_dir, tmp) -> dict:
     return out
 
 
-def distributed_parity(cache_dir, tmp) -> dict:
+def distributed_parity(tmp) -> dict:
     """One real 2-process jax.distributed run vs the single-process run."""
     single = _fleet(PARITY + ["--shard", "0:1"],
-                    tmp / "par_single.json", cache_dir)
+                    tmp / "par_single.json")
     dist = _fleet(PARITY + ["--processes", "2"],
-                  tmp / "par_dist.json", cache_dir)
+                  tmp / "par_dist.json")
     diffs = [abs(a - b) / max(abs(a), 1e-12) for a, b in
              zip(single["mean_latency"], dist["mean_latency"])]
     return {"grid_points": single["grid_points"],
@@ -114,7 +120,8 @@ _CHILD_SRC = r"""
 import json, sys, time
 sys.path.insert(0, sys.argv[2])
 from repro.runtime import cache as rcache
-rcache.enable_persistent_cache(sys.argv[1])
+if sys.argv[1] == "on":
+    rcache.enable_persistent_cache()
 import jax
 from repro.core import traffic
 from repro.core.simulator import Arch, SimConfig, simulate, sweep_topology
@@ -149,11 +156,11 @@ print("WALLS " + json.dumps(walls))
 """
 
 
-def _coldwarm_child(cache_dir, mode) -> dict:
+def _coldwarm_child(cache, mode) -> dict:
     proc = subprocess.run(
-        [sys.executable, "-c", _CHILD_SRC, str(cache_dir),
-         str(REPO / "src"), mode],
-        cwd=REPO, timeout=900, capture_output=True, text=True)
+        [sys.executable, "-c", _CHILD_SRC, cache, str(REPO / "src"), mode],
+        cwd=REPO, env=_CHILD_ENV, timeout=900, capture_output=True,
+        text=True)
     if proc.returncode != 0:
         raise RuntimeError(f"cold/warm child failed:\n{proc.stdout}\n"
                            f"{proc.stderr}")
@@ -163,19 +170,21 @@ def _coldwarm_child(cache_dir, mode) -> dict:
     raise RuntimeError(f"no WALLS line in child output:\n{proc.stdout}")
 
 
-def cold_vs_warm(tmp) -> dict:
-    """First-dispatch wall in a fresh process: empty cache vs populated.
+def cold_vs_warm() -> dict:
+    """First-dispatch wall in a fresh process: no cache vs populated.
 
-    The acceptance measurement is the AOT path (serialized executables —
-    the second process neither traces nor compiles); the jit-level
-    persistent cache is measured alongside for context (it removes XLA
-    compilation but still pays re-tracing).
+    Cold runs with the persistent cache off; a second process fills the
+    repository's persistent cache and a third one reads it (warm). The
+    acceptance measurement is the AOT path (serialized executables — the
+    warm process neither traces nor compiles); the jit-level persistent
+    cache is measured alongside for context (it removes XLA compilation
+    but still pays re-tracing).
     """
     out = {}
     for mode in ("aot", "jit"):
-        cdir = tmp / f"coldwarm-cache-{mode}"
-        cold = _coldwarm_child(cdir, mode)   # populates the empty cache
-        warm = _coldwarm_child(cdir, mode)   # fresh process, cache hits
+        cold = _coldwarm_child("off", mode)  # trace + compile, no cache
+        _coldwarm_child("on", mode)          # fills the persistent cache
+        warm = _coldwarm_child("on", mode)   # fresh process, cache hits
         out[mode] = {k: {"cold_s": cold[k], "warm_s": warm[k],
                          "warm_over_cold": warm[k] / cold[k]}
                      for k in cold}
@@ -191,10 +200,9 @@ def run() -> dict:
     t0 = time.time()
     with tempfile.TemporaryDirectory(prefix="bench-dist-") as td:
         tmp = Path(td)
-        cache_dir = tmp / "fleet-cache"
-        scaling = emulated_scaling(cache_dir, tmp)
-        parity = distributed_parity(cache_dir, tmp)
-        coldwarm = cold_vs_warm(tmp)
+        scaling = emulated_scaling(tmp)
+        parity = distributed_parity(tmp)
+    coldwarm = cold_vs_warm()
     result = {
         "scaling": scaling,
         "distributed_2proc": parity,

@@ -427,15 +427,16 @@ def distributed_smoke() -> None:
     grid = ["--chiplets", "4,9", "--placements", "2",
             "--workloads", "uniform,bursty", "--intervals", "6",
             "--seed", "0", "--dump-points"]
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    # The children run on the CPU backend: this process already holds the
+    # backend it started with, and an accelerator serves one process.
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
     with tempfile.TemporaryDirectory(prefix="fleet-smoke-") as td:
         outs = {}
         for tag, extra in (("single", ["--shard", "0:1"]),
                            ("dist", ["--processes", "2"])):
             out = Path(td) / f"{tag}.json"
             cmd = [sys.executable, "-m", "repro.launch.fleet",
-                   "--cache-dir", f"{td}/cache", "--out", str(out)] \
-                + grid + extra
+                   "--out", str(out)] + grid + extra
             proc = subprocess.run(cmd, cwd=REPO, env=env, timeout=600,
                                   capture_output=True, text=True)
             assert proc.returncode == 0, \

@@ -76,12 +76,8 @@ def init_distributed(*, coordinator: Optional[str] = None,
     if not 0 <= process_id < num_processes:
         raise ValueError(f"process_id {process_id} out of range for "
                          f"{num_processes} processes")
-    if collectives:
-        try:  # must land before the CPU client exists; older jax: no knob
-            jax.config.update("jax_cpu_collectives_implementation",
-                              collectives)
-        except Exception:  # pragma: no cover - jax version dependent
-            log.warning("could not select %r CPU collectives", collectives)
+    if collectives:  # must land before the CPU client exists
+        jax.config.update("jax_cpu_collectives_implementation", collectives)
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

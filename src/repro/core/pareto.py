@@ -798,7 +798,10 @@ def search_codesign(trace, sim, *, islands: int = None,
     host-driven loop over `sweep_topology_batch` (the parity oracle —
     different PRNG streams, same scoring path, same archive rules).
     Pass `devices` (more than one) to shard the island axis via
-    `GridSharding` when islands divide the device count evenly.
+    `GridSharding`; the island count must then divide over them. With
+    `devices=None` the search shards over `jax.devices()` when the island
+    count divides over them and runs on one device otherwise (the result
+    carries a `"sharding"` entry only when it was sharded).
 
     Returns the Pareto front as `"front"` entries — each a (topology,
     placement, knobs, objectives) record — plus the raw archive,
@@ -812,6 +815,10 @@ def search_codesign(trace, sim, *, islands: int = None,
     _check_codesign_params(generations, population, migrate_every, archive)
     cs, gs, rs = _check_topology_grids(sim, topo_grids)
     knobs, islands = _check_knob_grids(knob_grids, islands)
+    if devices is not None and len(devices) > 1 and islands % len(devices):
+        raise ValueError(
+            f"islands={islands} does not divide over {len(devices)} devices "
+            f"(the migration ring admits no padded islands)")
 
     if engine == "host":
         return _host_codesign(
@@ -831,29 +838,19 @@ def search_codesign(trace, sim, *, islands: int = None,
     w_axis = info["workloads"]
 
     devices = list(devices if devices is not None else jax.devices())
-    res = None
     sharding = None
     if len(devices) > 1 and islands % len(devices) == 0:
-        try:
-            from repro.core.distributed import GridSharding
+        from repro.core.distributed import GridSharding
 
-            gsh = GridSharding(islands, devices=devices,
-                               logical_axis="islands")
-            ov_s, w_s = gsh.shard((ov, weights))
-            topo_r, hyper_r, ext_r, mem_r, intra_r, frac_r, mask_r, \
-                dest_r = gsh.replicate((topo, hyper, ext, mem, intra,
-                                        ext_frac, t_mask, dest))
-            res = _codesign_jit(key, topo_r, ov_s, w_s, hyper_r, ext_r,
-                                mem_r, intra_r, frac_r, mask_r, dest_r,
-                                **static)
-            sharding = gsh.describe()
-        except Exception as e:  # pragma: no cover - device-layout dependent
-            import warnings
-            warnings.warn(f"sharded co-design search failed ({e!r}); "
-                          f"falling back to single-device path")
-            res = None
-            sharding = None
-    if res is None:
+        gsh = GridSharding(islands, devices=devices, logical_axis="islands")
+        ov_s, w_s = gsh.shard((ov, weights))
+        topo_r, hyper_r, ext_r, mem_r, intra_r, frac_r, mask_r, \
+            dest_r = gsh.replicate((topo, hyper, ext, mem, intra,
+                                    ext_frac, t_mask, dest))
+        res = _codesign_jit(key, topo_r, ov_s, w_s, hyper_r, ext_r,
+                            mem_r, intra_r, frac_r, mask_r, dest_r, **static)
+        sharding = gsh.describe()
+    else:
         res = _codesign_jit(key, topo, ov, weights, hyper, ext, mem, intra,
                             ext_frac, t_mask, dest, **static)
     # Counted after the launch (PR-5 convention): a raising compile never

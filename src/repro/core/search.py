@@ -495,7 +495,7 @@ def search_placement_islands(trace: dict, sim, *, islands: int = None,
     searches the best placement *per L_m operating point* — a joint
     placement x runtime-knob exploration (the concrete step toward the
     ROADMAP's joint search item). With more than one device the island
-    axis is sharded via NamedSharding (graceful single-device fallback).
+    axis is sharded via NamedSharding; a sharded run that fails raises.
 
     Returns the overall winner plus per-island bests/defaults/histories
     (`island_*` arrays, leading [K] axis), all from one `device_get`.
@@ -546,40 +546,29 @@ def search_placement_islands(trace: dict, sim, *, islands: int = None,
                   moves_hi=max(1, generations // 3))
 
     devices = list(devices if devices is not None else jax.devices())
-    res = None
     if len(devices) > 1:
-        try:
-            from repro.core.distributed import GridSharding
+        from repro.core.distributed import GridSharding
 
-            # The island axis shards over the fleet's "grid" mesh axis —
-            # with init_distributed up, across every host's devices. The
-            # shared trace/search inputs replicate fleet-wide; the result
-            # pytree is all-gathered so every process sees all islands.
-            gs = GridSharding(islands, devices=devices,
-                              logical_axis="islands")
-            carry_s, keys_s, ov_s = gs.shard((carry0, keys, ov))
-            ext_r, mem_r, intra_r, frac_r, mask_r, dpos_r, hyper_r, \
-                blocked_r, dest_r = gs.replicate(
-                    (ext, mem, intra, ext_frac, t_mask, default_pos,
-                     hyper, blocked, dest))
-            res = _search_islands_jit(
-                carry_s, keys_s, ext_r, mem_r, intra_r, frac_r, mask_r,
-                dpos_r, hyper_r, ov_s, blocked_r, dest_r, **static)
-            res = gs.gather(res)
-        except Exception as e:  # pragma: no cover - depends on device layout
-            import warnings
-            warnings.warn(f"sharded island search failed ({e!r}); falling "
-                          f"back to single-device path")
-            res = None
-            carry0 = jax.vmap(lambda _: _init_carry(init_pos))(
-                jnp.arange(islands))
-    if res is None:
+        # The island axis shards over the fleet's "grid" mesh axis — with
+        # init_distributed up, across every host's devices. The shared
+        # trace/search inputs replicate fleet-wide; the result pytree is
+        # all-gathered so every process sees all islands.
+        gs = GridSharding(islands, devices=devices, logical_axis="islands")
+        carry_s, keys_s, ov_s = gs.shard((carry0, keys, ov))
+        ext_r, mem_r, intra_r, frac_r, mask_r, dpos_r, hyper_r, \
+            blocked_r, dest_r = gs.replicate(
+                (ext, mem, intra, ext_frac, t_mask, default_pos,
+                 hyper, blocked, dest))
+        res = _search_islands_jit(
+            carry_s, keys_s, ext_r, mem_r, intra_r, frac_r, mask_r,
+            dpos_r, hyper_r, ov_s, blocked_r, dest_r, **static)
+        res = gs.gather(res)
+    else:
         res = _search_islands_jit(carry0, keys, ext, mem, intra, ext_frac,
                                   t_mask, default_pos, hyper, ov, blocked,
                                   dest, **static)
-    # Counted once per *successful* launch (a failed sharded attempt that
-    # fell back above raised before dispatching), preserving the
-    # one-search == one-dispatch accounting on every device layout.
+    # Counted after the launch: a raising compile never inflates the
+    # one-search == one-dispatch accounting.
     _sim._STATS["search_dispatches"] += 1
     host = jax.device_get(res)          # the ONE transfer for all islands
 

@@ -114,7 +114,8 @@ class SimConfig:
     # Run the interval-scan body as the fused `kernels.epoch_step` Pallas
     # kernel (interpret on CPU, compiled on TPU) instead of the XLA lax.scan
     # body. Applies to the RESIPI/RESIPI_ALL unpadded-topology paths; other
-    # configurations fall back to the scan body, which doubles as the
+    # configurations run the scan body (engine_stats()["kernel_traces"]
+    # counts the traces that took the kernel), which doubles as the
     # kernel's 1e-6 parity oracle (kernels/epoch_step/ref.py).
     epoch_kernel: bool = False
 
@@ -250,7 +251,8 @@ def _interval_metrics(g: jax.Array, wavelengths: jax.Array,
             dst_leg = jnp.where(chip_mask > 0, dst_leg, 0.0)
         inter_lat = (noc.access_latency(src_hops, gw_load)
                      + noc.gateway_latency(gw_load, lam)
-                     + dest @ dst_leg)                                 # [C]
+                     + jnp.matmul(dest, dst_leg,      # full f32 on TPU too
+                                  precision=jax.lax.Precision.HIGHEST))  # [C]
     if chip_mask is not None:
         inter_lat = jnp.where(chip_mask > 0, inter_lat, 0.0)
     mem_lat = noc.inter_chiplet_latency(mem_gw_load, lam_mem,
@@ -522,7 +524,9 @@ def make_step(sim: SimConfig, tables: dict, topo: Optional[dict] = None,
 # body. A warm jit cache leaves these untouched — tests/benches assert on it.
 # `search_dispatches` counts device-resident search executable launches
 # (repro.core.search): one whole annealed search == one dispatch.
-_STATS = {"traces": 0, "search_dispatches": 0}
+# `kernel_traces` counts the traces that took the fused epoch_step kernel,
+# so which body a run used is observable, not assumed.
+_STATS = {"traces": 0, "search_dispatches": 0, "kernel_traces": 0}
 
 # Config fields that `sweep` may override with runtime (traced) scalars.
 # All are scalar knobs that feed jnp comparisons/arithmetic — nothing that
@@ -548,14 +552,15 @@ def engine_stats() -> dict:
     """Engine instrumentation: scan-body trace count + table-cache stats."""
     info = build_selection_tables.cache_info()
     return {"simulate_traces": _STATS["traces"],
+            "kernel_traces": _STATS["kernel_traces"],
             "search_dispatches": _STATS["search_dispatches"],
             "selection_table_builds": info.misses,
             "selection_table_hits": info.hits}
 
 
 def reset_engine_stats() -> None:
-    _STATS["traces"] = 0
-    _STATS["search_dispatches"] = 0
+    for k in _STATS:
+        _STATS[k] = 0
 
 
 def clear_engine_caches() -> None:
@@ -654,14 +659,17 @@ def _scan_trace(state: SimState, xs, sim: SimConfig, tables: Optional[dict],
 
     With `sim.epoch_kernel` set the whole interval scan runs as the fused
     `kernels.epoch_step` Pallas kernel (one kernel launch for T intervals)
-    on the configurations it supports; everything else — and every parity
-    oracle — takes the lax.scan body below. Both bodies share this counter:
-    one trace per scan, whichever engine executes it.
+    on the configurations it supports: RESIPI/RESIPI_ALL on an unpadded
+    topology. PROWAVES/AWGR and the padded-topology paths, and every parity
+    oracle, take the lax.scan body below. Both bodies share the trace
+    counter (one trace per scan, whichever engine executes it); kernel
+    traces are also counted on their own (`engine_stats()["kernel_traces"]`).
     """
     _STATS["traces"] += 1
     if sim.epoch_kernel and topo is None \
             and sim.arch in (Arch.RESIPI, Arch.RESIPI_ALL):
         from repro.kernels.epoch_step.ops import epoch_run_pallas
+        _STATS["kernel_traces"] += 1
         return epoch_run_pallas(state, xs, sim, tables,
                                 dest=dest, faulted=faulted)
     step = make_step(sim, tables, topo, faulted=faulted, dest=dest)
@@ -1443,9 +1451,9 @@ def shard_sweep(traces, sim: SimConfig, *, devices=None, **grids) -> dict:
     all-gathered, so every process returns the full grid). K is padded to
     a multiple of the device count by repeating the last grid point —
     logged, sliced off the results, and reported as `summary["pad_lanes"]`
-    plus a top-level `"sharding"` dict (no silent caps). Degrades
-    gracefully to the single-device `sweep_topology` path when one device
-    is present or sharding fails.
+    plus a top-level `"sharding"` dict (no silent caps). With one device
+    it runs the single-device `sweep_topology` path; a sharded run that
+    fails raises rather than quietly rerunning on one device.
 
     Accepts a single trace dict or a list/stacked batch (leading [N] axis
     in the results, as `sweep_topology_batch`).
@@ -1463,33 +1471,22 @@ def shard_sweep(traces, sim: SimConfig, *, devices=None, **grids) -> dict:
                 out["summary"]["mean_latency"]).shape[-1]),
             "pad_lanes": 0, "devices": 1, "processes": 1})
 
-    try:
-        sim_p, topo, ov, c_max = _prepare_topology_sweep(sim, grids)
-        batch = stack_traces(traces, pad=True) \
-            if isinstance(traces, (list, tuple)) else traces
-        ext, mem, intra, ext_frac, t_mask, dest = _topo_trace_arrays(
-            batch, c_max)
+    sim_p, topo, ov, c_max = _prepare_topology_sweep(sim, grids)
+    batch = stack_traces(traces, pad=True) \
+        if isinstance(traces, (list, tuple)) else traces
+    ext, mem, intra, ext_frac, t_mask, dest = _topo_trace_arrays(
+        batch, c_max)
 
-        k = int(topo["n_chiplets"].shape[0])
-        gs = GridSharding(k, devices=devices)
-        topo = gs.shard(topo)
-        ov = gs.shard(ov)
-        ext, mem, intra, ext_frac, t_mask, dest = gs.replicate(
-            (ext, mem, intra, ext_frac, t_mask, dest))
-        fn = _sweep_topology_batch_jit if batched else _sweep_topology_jit
-        out = fn(ext, mem, intra, ext_frac, t_mask, topo, ov, dest,
-                 sim=sim_p)
-        out = gs.gather(out, axis=1 if batched else 0)
-        return _sharding_note(out, gs.describe())
-    except Exception as e:  # pragma: no cover - depends on device layout
-        import warnings
-        warnings.warn(f"sharded sweep failed ({e!r}); falling back to "
-                      f"single-device path")
-        out = single_call(traces, sim, **grids)
-        return _sharding_note(out, {
-            "grid_points": int(np.asarray(
-                out["summary"]["mean_latency"]).shape[-1]),
-            "pad_lanes": 0, "devices": 1, "processes": 1})
+    k = int(topo["n_chiplets"].shape[0])
+    gs = GridSharding(k, devices=devices)
+    topo = gs.shard(topo)
+    ov = gs.shard(ov)
+    ext, mem, intra, ext_frac, t_mask, dest = gs.replicate(
+        (ext, mem, intra, ext_frac, t_mask, dest))
+    fn = _sweep_topology_batch_jit if batched else _sweep_topology_jit
+    out = fn(ext, mem, intra, ext_frac, t_mask, topo, ov, dest, sim=sim_p)
+    out = gs.gather(out, axis=1 if batched else 0)
+    return _sharding_note(out, gs.describe())
 
 
 # ---------------------------------------------------------------------------
@@ -1530,7 +1527,7 @@ def sweep_workload(specs, sim: SimConfig, *, seed: int = 0, keys=None,
     grids, overrides, destination matrices) partitions over the "grid"
     mesh axis, K padded to a device multiple by repeating the last lane
     (logged; reported as `summary["pad_lanes"]` + a `"sharding"` dict and
-    sliced off the results). Falls back to the unsharded call on failure.
+    sliced off the results). A sharded run that fails raises.
 
     `gen_chiplets` pins the chiplet count traces are generated at (default:
     the largest `n_chiplets` in the grid). An emulated-host worker running
@@ -1573,11 +1570,9 @@ def sweep_workload(specs, sim: SimConfig, *, seed: int = 0, keys=None,
         ext, mem, intra, ext_frac, t_mask, dmat = _topo_trace_arrays(
             batch, c_max)
         if devices is not None and len(devices) > 1:
-            out = _shard_workload(
+            return _shard_workload(
                 (ext, mem, intra, ext_frac, t_mask, topo, ov, dmat),
                 devices, lambda a, _: _sweep_workload_topo_jit(*a, sim=sim_p))
-            if out is not None:
-                return out
         return _sweep_workload_topo_jit(ext, mem, intra, ext_frac, t_mask,
                                         topo, ov, dmat, sim=sim_p)
 
@@ -1593,13 +1588,11 @@ def sweep_workload(specs, sim: SimConfig, *, seed: int = 0, keys=None,
     ext, mem, intra, ext_frac, t_mask, dmat = _trace_arrays(batch)
     tables = selection_tables_jax(sim.cfg)
     if devices is not None and len(devices) > 1:
-        out = _shard_workload(
+        return _shard_workload(
             (ext, mem, intra, ext_frac, t_mask, ov, dmat), devices,
             lambda a, rep: _sweep_workload_jit(
                 a[0], a[1], a[2], a[3], a[4], rep[0], a[5], a[6], sim=sim),
             replicated=(tables,))
-        if out is not None:
-            return out
     return _sweep_workload_jit(ext, mem, intra, ext_frac, t_mask,
                                tables, ov, dmat, sim=sim)
 
@@ -1610,22 +1603,15 @@ def _shard_workload(args, devices, call, replicated=()):
     `args` is a tuple of leading-K pytrees (None leaves welcome);
     `replicated` holds fleet-global extras (e.g. selection tables).
     `call(sharded_args, replicated_extras)` launches the jitted entry
-    point. Returns the gathered result dict with sharding metadata, or
-    None to signal fallback to the unsharded path.
+    point. Returns the gathered result dict with sharding metadata.
     """
     from repro.core.distributed import GridSharding
 
-    try:
-        k = int(args[0].shape[0])
-        gs = GridSharding(k, devices=devices)
-        out = call(gs.shard(args), gs.replicate(replicated))
-        out = gs.gather(out)
-        return _sharding_note(out, gs.describe())
-    except Exception as e:  # pragma: no cover - depends on device layout
-        import warnings
-        warnings.warn(f"sharded workload sweep failed ({e!r}); falling "
-                      f"back to the unsharded path")
-        return None
+    k = int(args[0].shape[0])
+    gs = GridSharding(k, devices=devices)
+    out = call(gs.shard(args), gs.replicate(replicated))
+    out = gs.gather(out)
+    return _sharding_note(out, gs.describe())
 
 
 class SimSession:
@@ -1759,10 +1745,12 @@ def session_tick(states: SimState, batch: dict, tables: dict,
 
     `batch` is a lane-stacked chunk dict: ext_load [B, T, C], mem_load
     [B, T], int_load [B, T, C], ext_frac [B], t_mask [B, T]. Lane k steps
-    exactly like `SimSession.step_chunk` on the same chunk (bit-parity
-    pinned by tests/test_serve.py); an all-masked lane freezes its carry
-    and contributes zero to every sum, so the server can park empty,
-    retrying, or draining lanes without changing the executable's shape.
+    exactly like `SimSession.step_chunk` on the same chunk (bit-parity on
+    the CPU pinned by tests/test_serve.py; 1e-6 on a TPU, where the
+    batched reductions may run in another order); an all-masked lane
+    freezes its carry and contributes zero to every sum, so the server can
+    park empty, retrying, or draining lanes without changing the
+    executable's shape.
 
     `frame` (optional) is ONE fault frame (gw_ok [T, C, G] / stuck_on
     [T, C, G] / drift_db [T]) shared by every lane — faults live on

@@ -11,7 +11,10 @@ parity oracle (ref.py, 1e-6 in interpret mode).
 
 Grid: (T // t_chunk,). Per-chiplet arrays ride in VMEM lane-padded to 128
 (compiled mode); per-interval scalars (mem load, t_mask, loss drift) ride in
-SMEM rows like noc_step's cycle masks. Runtime sweepable knobs (l_m,
+SMEM like noc_step's cycle masks, as [n_steps, 1, t_chunk] arrays blocked
+(1, 1, t_chunk): the block's last two dims equal the array's, which the
+TPU's (8, 128) tiling rule accepts at any grid length, and only one chunk
+sits in the 1 MiB of SMEM at a time. Runtime sweepable knobs (l_m,
 max/min_gateways, buffer_sat, wavelengths) arrive as a small SMEM params
 vector because `sweep` may trace them.
 
@@ -28,11 +31,17 @@ prefix; memory-gateway kappas are constant (1/(M-i)) and never switch.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128   # TPU lane width: the chiplet axis pads to this for compilation
+_DB_TO_LN = math.log(10.0) / 10.0   # 10 ** (x / 10) == exp(x * _DB_TO_LN)
+# Full f32 contraction on the MXU (the default may round operands to bf16),
+# so the compiled kernel matches the f32 scan body.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 # out_scal column layout (wrapper slices by these indices)
 COL_LATENCY = 0
@@ -96,8 +105,9 @@ def _epoch_kernel(*refs, t_chunk: int, n_steps: int, n_chiplets: int,
 
     # Strictly-lower-triangular chain-prefix matrix: prefix = tot @ LT sums
     # the per-chiplet active totals of every chiplet EARLIER in the chain.
-    rows = jax.lax.broadcasted_iota(jnp.float32, (n_lanes, n_lanes), 0)
-    cols = jax.lax.broadcasted_iota(jnp.float32, (n_lanes, n_lanes), 1)
+    # (Mosaic builds iotas in integers only.)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n_lanes, n_lanes), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (n_lanes, n_lanes), 1)
     lt_mat = (rows < cols).astype(jnp.float32)
 
     # --- queueing closures (op-for-op noc.NocModel) ------------------------
@@ -133,7 +143,7 @@ def _epoch_kernel(*refs, t_chunk: int, n_steps: int, n_chiplets: int,
             tot = tot + l
         gt = jnp.sum(tot) + m_f
         prefix = jax.lax.dot_general(
-            tot, lt_mat, (((1,), (0,)), ((), ())),
+            tot, lt_mat, (((1,), (0,)), ((), ())), precision=_HIGHEST,
             preferred_element_type=jnp.float32)           # [1, P]
         run = jnp.zeros_like(tot)
         ks = []
@@ -147,21 +157,17 @@ def _epoch_kernel(*refs, t_chunk: int, n_steps: int, n_chiplets: int,
     def interval_body(t, g):
         ext = ext_ref[t, :][None, :].astype(jnp.float32)        # [1, P]
         intra = intra_ref[t, :][None, :].astype(jnp.float32)    # [1, P]
-        mem = mem_ref[0, t].astype(jnp.float32)
-        tm = tmask_ref[0, t].astype(jnp.float32)
-        drift = drift_ref[0, t].astype(jnp.float32)
+        mem = mem_ref[0, 0, t].astype(jnp.float32)
+        tm = tmask_ref[0, 0, t].astype(jnp.float32)
+        drift = drift_ref[0, 0, t].astype(jnp.float32)
 
         # Desired / usable / lit slot masks per static slot index.
         des = [(jnp.float32(s) < g).astype(jnp.float32)
                for s in range(g_slots)]
         if faulted:
-            ok = [pl.load(gwok_ref, (pl.dslice(s, 1), pl.dslice(t, 1),
-                                     slice(None)))
-                  .reshape(1, n_lanes).astype(jnp.float32)
+            ok = [gwok_ref[s, pl.ds(t, 1), :].astype(jnp.float32)
                   for s in range(g_slots)]
-            st = [pl.load(stuck_ref, (pl.dslice(s, 1), pl.dslice(t, 1),
-                                      slice(None)))
-                  .reshape(1, n_lanes).astype(jnp.float32)
+            st = [stuck_ref[s, pl.ds(t, 1), :].astype(jnp.float32)
                   for s in range(g_slots)]
             usable = [d * o for d, o in zip(des, ok)]
             lit = [jnp.maximum(u, s_ * o)
@@ -195,11 +201,11 @@ def _epoch_kernel(*refs, t_chunk: int, n_steps: int, n_chiplets: int,
             # matmuls over the destination matrix (no [P, P] materialization
             # or transposes; the squared weight factors elementwise).
             recv = jax.lax.dot_general(
-                ext, dmat, (((1,), (0,)), ((), ())),
+                ext, dmat, (((1,), (0,)), ((), ())), precision=_HIGHEST,
                 preferred_element_type=jnp.float32)           # [1, P]
             phi = (jax.lax.dot_general(
                 ext * ext, dmat * dmat, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                precision=_HIGHEST, preferred_element_type=jnp.float32)
                    / jnp.maximum(recv * recv, 1e-12))
             burst_scale = (1.0 + (burstiness - 1.0) * phi) / burstiness
             dst_gw = recv / g_eff_f
@@ -207,6 +213,7 @@ def _epoch_kernel(*refs, t_chunk: int, n_steps: int, n_chiplets: int,
             inter = (access_lat(src, gw_load) + gateway_lat(gw_load)
                      + jax.lax.dot_general(
                          dst_leg, dmat, (((1,), (1,)), ((), ())),
+                         precision=_HIGHEST,
                          preferred_element_type=jnp.float32))
         else:
             recv = None
@@ -231,7 +238,8 @@ def _epoch_kernel(*refs, t_chunk: int, n_steps: int, n_chiplets: int,
         for l in lit:
             n_lit = n_lit + jnp.sum(l * lmask)
         lit_w = (n_lit + m_f) * lam
-        laser = lit_w * laser_mw * (10.0 ** (access_db / 10.0))
+        # 10 ** (dB / 10) as an exponential: Mosaic has no scalar powf.
+        laser = lit_w * laser_mw * jnp.exp(access_db * _DB_TO_LN)
         tia = lit_w * tia_mw
         tuning = (lit_w + lit_w) * tuning_mw
         driver = lit_w * driver_mw
@@ -279,16 +287,16 @@ def _epoch_kernel(*refs, t_chunk: int, n_steps: int, n_chiplets: int,
             failed = jnp.float32(0.0)
 
         # --- per-interval records (t_valid-masked like the scan body) ---
-        lane = jax.lax.broadcasted_iota(jnp.float32, (1, s_cols), 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, s_cols), 1)
         vals = (lat * tm, total * tm, laser * tm, reconf * tm, minter * tm,
                 sat * tm, failed * tm)
         row = jnp.zeros((1, s_cols), jnp.float32)
         for k, v in enumerate(vals):
-            row = row + v * (lane == jnp.float32(k)).astype(jnp.float32)
-        pl.store(scal_ref, (pl.dslice(t, 1), slice(None)), row)
-        pl.store(g_out_ref, (pl.dslice(t, 1), slice(None)), g_eff * tm)
-        pl.store(gdes_ref, (pl.dslice(t, 1), slice(None)), g * tm)
-        pl.store(gwl_ref, (pl.dslice(t, 1), slice(None)), gw_load * tm)
+            row = row + v * (lane == k).astype(jnp.float32)
+        scal_ref[pl.ds(t, 1), :] = row
+        g_out_ref[pl.ds(t, 1), :] = g_eff * tm
+        gdes_ref[pl.ds(t, 1), :] = g * tm
+        gwl_ref[pl.ds(t, 1), :] = gw_load * tm
 
         # Masked intervals freeze the controller carry.
         return tm * g_new + (1.0 - tm) * g

@@ -166,8 +166,10 @@ def epoch_run_pallas(state, xs, sim, tables: dict, *,
                              + pwr.controller_inc_uw) / 1000.0),
         reconfig_nj=float(pwr.pcmc_reconfig_nj))
 
-    row_spec = functools.partial(pl.BlockSpec, (1, t_chunk),
-                                 lambda i: (i, 0),
+    # Per-interval scalar rows as [n_steps, 1, t_chunk]: one (1, 1, t_chunk)
+    # SMEM block per grid step meets the tiling rule at every length.
+    row_spec = functools.partial(pl.BlockSpec, (1, 1, t_chunk),
+                                 lambda i: (i, 0, 0),
                                  memory_space=pltpu.SMEM)
     whole = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
     chunk = lambda width: pl.BlockSpec((t_chunk, width), lambda i: (i, 0))
@@ -186,9 +188,9 @@ def epoch_run_pallas(state, xs, sim, tables: dict, *,
         whole((1, p)),                                        # g0
         whole((1, p)),                                        # lane mask
     ]
-    inputs = [ext, intra, mem.reshape(n_steps, t_chunk),
-              t_mask_p.reshape(n_steps, t_chunk),
-              drift.reshape(n_steps, t_chunk), params, srch, gwdb,
+    inputs = [ext, intra, mem.reshape(n_steps, 1, t_chunk),
+              t_mask_p.reshape(n_steps, 1, t_chunk),
+              drift.reshape(n_steps, 1, t_chunk), params, srch, gwdb,
               g0[None, :], lmask[None, :]]
     if use_dest:
         in_specs.append(whole((p, p)))

@@ -27,6 +27,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import resolve_interpret
 
 LANES = 128   # TPU lane width: the router axis pads to this for compilation
+# Full f32 contraction on the MXU (the default may round operands to bf16),
+# so the compiled kernel matches the f32 reference.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _noc_kernel(arrivals_ref, tmask_ref, next_mat_ref, drain_ref, buf_ref,
@@ -56,7 +59,7 @@ def _noc_kernel(arrivals_ref, tmask_ref, next_mat_ref, drain_ref, buf_ref,
         # Per-cycle time-validity scalar (SMEM): a masked cycle freezes
         # the whole network state, so time-padded batches match their
         # unpadded originals exactly.
-        tm = tmask_ref[0, t].astype(jnp.float32)
+        tm = tmask_ref[0, 0, t].astype(jnp.float32)
         # Dead-lane enforcement: invalid (padded or faulted-this-cycle)
         # lanes can never hold or emit flits, whatever the caller put in
         # their arrival/buffer slots.
@@ -68,6 +71,7 @@ def _noc_kernel(arrivals_ref, tmask_ref, next_mat_ref, drain_ref, buf_ref,
         # desired inflow at each destination: send @ nmat  ([1,R]@[R,R])
         inflow_want = jax.lax.dot_general(
             send, nmat, (((1,), (0,)), ((), ())),
+            precision=_HIGHEST,
             preferred_element_type=jnp.float32)                 # [1, R]
         space = jnp.maximum(buf - occ, 0.0)
         scale_dst = jnp.where(inflow_want > 0.0,
@@ -76,10 +80,12 @@ def _noc_kernel(arrivals_ref, tmask_ref, next_mat_ref, drain_ref, buf_ref,
         # per-source allowed send = send * scale[next(source)]
         scale_src = jax.lax.dot_general(
             scale_dst, nmat, (((1,), (1,)), ((), ())),
+            precision=_HIGHEST,
             preferred_element_type=jnp.float32)                 # [1, R]
         moved = send * scale_src
         inflow = jax.lax.dot_general(
             moved, nmat, (((1,), (0,)), ((), ())),
+            precision=_HIGHEST,
             preferred_element_type=jnp.float32)
         # Flits routed INTO a dead lane are lost at the broken link (the
         # sender already moved them out); on clean paths nothing routes
@@ -195,9 +201,11 @@ def noc_run_pallas(arrivals: jax.Array, next_mat: jax.Array,
         grid=(n_steps,),
         in_specs=[
             pl.BlockSpec((t_chunk, r), lambda i: (i, 0)),
-            # per-cycle validity scalars ride in SMEM, one t_chunk row per
-            # grid step — R times smaller than materializing a [T, R] mask
-            pl.BlockSpec((1, t_chunk), lambda i: (i, 0),
+            # per-cycle validity scalars ride in SMEM, R times smaller than
+            # a [T, R] mask, as [n_steps, 1, t_chunk] blocked (1, 1,
+            # t_chunk): last two block dims equal the array's, so the
+            # (8, 128) tiling rule holds at every grid length
+            pl.BlockSpec((1, 1, t_chunk), lambda i: (i, 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((r, r), lambda i: (0, 0)),
             pl.BlockSpec((1, r), lambda i: (0, 0)),
@@ -212,6 +220,6 @@ def noc_run_pallas(arrivals: jax.Array, next_mat: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((1, r), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((1, r), jnp.float32)] * 3,
         interpret=interpret,
-    )(arrivals, t_mask.reshape(n_steps, t_chunk), next_mat,
+    )(arrivals, t_mask.reshape(n_steps, 1, t_chunk), next_mat,
       drain_rate[None, :], buf_cap[None, :], mask_in)
     return resid[0, :r_in], occ[0, :r_in], drained[0, :r_in]

@@ -12,6 +12,7 @@ Three ways to run it::
     python -m repro.launch.fleet --chiplets 4,16,36 --intervals 16
 
     # launcher: spawn N local worker processes, one jax.distributed mesh
+    # (the workers run on the CPU backend: a rehearsal of a multi-host fleet)
     python -m repro.launch.fleet --processes 2 --out fleet.json
 
     # one worker of an externally-orchestrated fleet (one per host)
@@ -127,7 +128,7 @@ def run_sweep(args, *, shard=None) -> dict:
                             if args.process_id is not None else None,
                             process_id=args.process_id)
     if not args.no_cache:
-        rcache.enable_persistent_cache(args.cache_dir)
+        rcache.enable_persistent_cache()
 
     import jax
     import numpy as np
@@ -204,10 +205,14 @@ def _free_port() -> int:
 
 def launch_local_fleet(args) -> int:
     """Spawn `--processes` local workers sharing one jax.distributed mesh
-    (the single-machine stand-in for one-worker-per-host orchestration)."""
+    (the single-machine stand-in for one-worker-per-host orchestration).
+
+    The workers run on the CPU backend (JAX_PLATFORMS=cpu): an accelerator
+    belongs to one process at a time, so local workers sharing a host's
+    chip would fail or hang. This launcher never imports jax itself."""
     port = _free_port()
     coord = f"127.0.0.1:{port}"
-    env_base = os.environ.copy()
+    env_base = dict(os.environ, JAX_PLATFORMS="cpu")
     if args.local_device_count:
         flags = env_base.get("XLA_FLAGS", "")
         env_base["XLA_FLAGS"] = (
@@ -243,8 +248,6 @@ def _passthrough(args):
            "--seed", str(args.seed),
            "--reps", str(args.reps),
            "--arch", args.arch]
-    if args.cache_dir:
-        out += ["--cache-dir", args.cache_dir]
     if args.no_cache:
         out += ["--no-cache"]
     if args.dump_points:
@@ -259,7 +262,9 @@ def build_parser() -> argparse.ArgumentParser:
                     "(chiplets x placements x workloads)")
     p.add_argument("--processes", type=int, default=None,
                    help="fleet size; without --process-id, spawn this many "
-                        "local workers")
+                        "local workers on the CPU backend (JAX_PLATFORMS=cpu"
+                        ": a CPU rehearsal of a multi-host fleet, since one "
+                        "chip serves one process)")
     p.add_argument("--process-id", type=int, default=None,
                    help="this worker's rank (externally orchestrated fleet)")
     p.add_argument("--coordinator", default=None,
@@ -282,9 +287,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--reps", type=int, default=1,
                    help="timed repetitions after the first call")
     p.add_argument("--arch", default="resipi")
-    p.add_argument("--cache-dir", default=None,
-                   help="persistent compilation cache directory")
-    p.add_argument("--no-cache", action="store_true")
+    p.add_argument("--no-cache", action="store_true",
+                   help="skip the persistent compilation cache (otherwise "
+                        "$JAX_COMPILATION_CACHE_DIR, else .jax_cache in the "
+                        "checkout)")
     p.add_argument("--dump-points", action="store_true",
                    help="include per-point mean latencies in the JSON")
     p.add_argument("--out", default=None, help="result JSON path")

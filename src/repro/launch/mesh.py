@@ -3,26 +3,15 @@
 A FUNCTION, not a module-level constant: importing this module never touches
 jax device state, so tests and benches keep their 1-CPU view while
 dryrun.py (which sets XLA_FLAGS first) sees 512 placeholder devices.
-
-Version compat: `jax.sharding.AxisType` (and the `axis_types` kwarg of
-`jax.make_mesh`) only exist in newer jax releases. On older jax we fall back
-to a plain mesh — every axis there is implicitly Auto anyway.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -40,8 +40,10 @@ import numpy as np
 
 log = logging.getLogger("repro.runtime.cache")
 
-ENV_CACHE_DIR = "REPRO_CACHE_DIR"
-_DEFAULT_DIR = "~/.cache/repro-jax-cache"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# The fallback lives inside the checkout (listed in .gitignore): the path is
+# part of the cache key, so it must not move between runs.
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 _CACHE = {"dir": None}
 _AOT: Dict[tuple, "AotEntry"] = {}
@@ -56,24 +58,24 @@ AOT_ENTRY_POINTS = ("simulate", "sweep", "sweep_topology", "session_tick",
 # ---------------------------------------------------------------------------
 
 def enable_persistent_cache(cache_dir: Optional[str] = None) -> pathlib.Path:
-    """Point jax's persistent compilation cache at `cache_dir` (created if
-    missing; default $REPRO_CACHE_DIR or ~/.cache/repro-jax-cache).
+    """Turn on jax's persistent compilation cache (directory created if
+    missing).
 
+    The directory is `$JAX_COMPILATION_CACHE_DIR` when that is set, else
+    `DEFAULT_CACHE_DIR` (`.jax_cache` at the root of the checkout).
+    `cache_dir` overrides both; only tests pass it, to isolate a cache.
     The min-compile-time and min-entry-size thresholds are opened up so
     every engine executable lands in the cache — the whole point is
     eliminating sub-second cold compiles, which the defaults skip.
     Idempotent; returns the resolved directory.
     """
-    path = pathlib.Path(
-        cache_dir or os.environ.get(ENV_CACHE_DIR, _DEFAULT_DIR)
-    ).expanduser()
+    if cache_dir is None:
+        cache_dir = os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
+    path = pathlib.Path(cache_dir).expanduser()
     path.mkdir(parents=True, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:  # newer jax: also gate on entry size; -1 = cache everything
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # pragma: no cover - jax version dependent
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _CACHE["dir"] = path
     log.info("persistent compilation cache at %s", path)
     return path

@@ -540,12 +540,12 @@ class SessionServer:
 
 
 def replay_standalone(sim: SimConfig, sess: ServeSession) -> dict:
-    """Re-run a served session through a standalone `SimSession`,
-    bit-exactly: same chunks, same placements, same shared fault frames,
-    in served order. Returns the standalone whole-stream summary — the
-    acceptance-criterion check that continuous batching is free
-    (tests/test_serve.py and bench_serve.py compare against
-    `sess.summary()`)."""
+    """Re-run a served session through a standalone `SimSession`: same
+    chunks, same placements, same shared fault frames, in served order.
+    Returns the standalone whole-stream summary — the check that
+    continuous batching is free (tests/test_serve.py and bench_serve.py
+    compare it with `sess.summary()` bit for bit on the CPU; on a TPU the
+    batched tick may reduce in another order, 1 ulp apart)."""
     from repro.core.faults import attach_faults
 
     if not sess.served_log:

@@ -219,7 +219,7 @@ specs = [traffic.UniformSpec(n_intervals=6),
          traffic.UniformSpec(n_intervals=6)]
 import warnings
 with warnings.catch_warnings():
-    warnings.simplefilter("error")    # sharded fallback warning = failure
+    warnings.simplefilter("error")    # a warning on the sharded path fails
     a = sweep_workload(specs, sim, n_chiplets=[4, 9, 16],
                        devices=jax.devices())
 b = sweep_workload(specs, sim, n_chiplets=[4, 9, 16])

@@ -16,8 +16,9 @@ literally `lax.scan(make_step(...))`, i.e. what every entry point runs when
     numbers with `epoch_kernel=True`;
   * compile-once discipline survives: one scan-body trace per shape, warm
     calls hit the cache;
-  * the arch guard (PROWAVES/AWGR fall back to the scan body at the
-    `_scan_trace` gate; the raw kernel op rejects them loudly).
+  * the arch guard (PROWAVES/AWGR run the scan body at the `_scan_trace`
+    gate, which counts kernel traces apart; the raw kernel op rejects them
+    loudly).
 """
 import dataclasses
 
@@ -129,7 +130,7 @@ def test_kernel_matches_reference_faults_dest_tmask():
 @pytest.mark.parametrize("arch", [Arch.PROWAVES, Arch.AWGR])
 def test_kernel_rejects_unsupported_arch(arch):
     """The raw op refuses non-RESIPI controllers (their lambda controllers
-    are not fused); the engine-level gate falls back silently instead."""
+    are not fused); the engine-level gate runs the scan body instead."""
     sim = SIM.with_arch(arch)
     tr = traffic.generate(traffic.UniformSpec(n_intervals=8),
                           jax.random.PRNGKey(5))
@@ -250,6 +251,7 @@ def test_kernel_compile_once():
     S.simulate(tr, SIM_K)
     stats = S.engine_stats()
     assert stats["simulate_traces"] == 1, stats
+    assert stats["kernel_traces"] == 1, stats      # the kernel body ran
     S.simulate(tr, SIM_K)
     S.simulate(dict(tr, ext_load=tr["ext_load"] * 2.0), SIM_K)
     assert S.engine_stats()["simulate_traces"] == 1, S.engine_stats()

@@ -376,6 +376,14 @@ def test_codesign_rejects_non_integer_islands(base):
         search_codesign(None, base, islands=2.5)
 
 
+def test_codesign_rejects_islands_not_dividing_devices(base):
+    """Explicit devices are a sharding request: an island count that does
+    not divide over them raises instead of quietly running on one."""
+    devices = jax.devices()[:1] * 2
+    with pytest.raises(ValueError, match="does not divide over 2 devices"):
+        search_codesign(None, base, islands=3, devices=devices)
+
+
 def test_codesign_rejects_unknown_engine(base):
     with pytest.raises(ValueError, match="unknown engine"):
         search_codesign(None, base, engine="magic")

@@ -47,8 +47,10 @@ def _sim() -> SimConfig:
 
 def _trace(seed: int = 0, t: int = T_TOTAL) -> dict:
     # x2 load: enough offered traffic that halving the gateways congests
-    # the survivors past the 10% detection band (calibrated: storm chunks
-    # run 13-18% over baseline, healthy phase noise stays under 5%).
+    # the survivors past the 10% detection band (with jax 0.9's PRNG
+    # stream the first storm chunk runs 3% over baseline in a light phase
+    # and the next three 11-285% over; healthy phase noise stays under
+    # 5%).
     tr = traffic.generate_trace("dedup", t, jax.random.PRNGKey(seed))
     for k in ("ext_load", "mem_load", "int_load"):
         tr[k] = jnp.asarray(tr[k]) * LOAD_SCALE
@@ -252,10 +254,18 @@ def test_baseline_freezes_during_breach():
         faulted = injector.inject(ch, runtime.current_cfg, t0)
         out = runtime.observe(faulted)
         baselines.append((out["breach"], out["baseline"]))
-    breached = [b for br, b in baselines if br]
-    assert breached, "storm never breached — test setup is wrong"
-    frozen = baselines[STORM_T0 // CHUNK - 1][1]
-    for br, b in baselines[STORM_T0 // CHUNK:]:
-        if br:
-            assert b == pytest.approx(frozen), \
-                "baseline chased the degraded latency"
+    # The storm's first chunk may fall in a light phase of the trace and
+    # stay in band (the baseline then legitimately takes it in), so the
+    # reference for each run of consecutive breaches is the baseline after
+    # the last in-band chunk before that run.
+    breached = [i for i, (br, _) in enumerate(baselines) if br]
+    assert any(i + 1 in breached for i in breached), \
+        "storm never breached twice in a row — test setup is wrong"
+    assert min(breached) >= STORM_T0 // CHUNK
+    for i in breached:
+        start = i
+        while baselines[start - 1][0]:
+            start -= 1
+        frozen = baselines[start - 1][1]
+        assert baselines[i][1] == pytest.approx(frozen), \
+            "baseline chased the degraded latency"
