@@ -91,6 +91,7 @@ from repro.core.selection import (N_DEFAULT_EDGE_SLOTS,
                                   padded_selection_tables_jax,
                                   resolve_gateway_positions,
                                   selection_tables_jax)
+from repro.runtime import spans
 
 
 class Arch(enum.Enum):
@@ -1066,6 +1067,7 @@ def rebuild_selection_tables(cfg: NetworkConfig) -> dict:
 SelectionTables_rebuild = rebuild_selection_tables
 
 
+@spans.root("sim.stack_traces")
 def stack_traces(traces: List[dict], *, pad: bool = False) -> dict:
     """Stack N traces along a new leading batch axis.
 
@@ -1171,6 +1173,7 @@ def _check_sweep_fields(fields) -> Dict[str, jax.Array]:
     return ov
 
 
+@spans.root("sim.sweep_batch")
 def sweep_batch(traces, sim: SimConfig, **fields) -> dict:
     """Full DSE grid in ONE compiled call: N traces x K parameter values.
 
@@ -1182,8 +1185,10 @@ def sweep_batch(traces, sim: SimConfig, **fields) -> dict:
         if isinstance(traces, (list, tuple)) else traces
     ov = _check_sweep_fields(fields)
     ext, mem, intra, ext_frac, t_mask, dest = _trace_arrays(batch)
-    return _sweep_batch_jit(ext, mem, intra, ext_frac, t_mask,
-                            selection_tables_jax(sim.cfg), ov, dest, sim=sim)
+    with spans.span("sim.dispatch"):
+        return _sweep_batch_jit(ext, mem, intra, ext_frac, t_mask,
+                                selection_tables_jax(sim.cfg), ov, dest,
+                                sim=sim)
 
 
 def sweep_faults(trace: dict, sim: SimConfig, frames, **fields) -> dict:
@@ -1407,6 +1412,7 @@ def sweep_topology(trace: dict, sim: SimConfig, **grids) -> dict:
                                dest, sim=sim_p)
 
 
+@spans.root("sim.sweep_topology_batch")
 def sweep_topology_batch(traces, sim: SimConfig, *, devices=None,
                          **grids) -> dict:
     """N traces x K topologies in ONE compiled call ([N, K] results).
@@ -1422,8 +1428,9 @@ def sweep_topology_batch(traces, sim: SimConfig, *, devices=None,
         if isinstance(traces, (list, tuple)) else traces
     sim_p, topo, ov, c_max = _prepare_topology_sweep(sim, grids)
     ext, mem, intra, ext_frac, t_mask, dest = _topo_trace_arrays(batch, c_max)
-    return _sweep_topology_batch_jit(ext, mem, intra, ext_frac, t_mask,
-                                     topo, ov, dest, sim=sim_p)
+    with spans.span("sim.dispatch"):
+        return _sweep_topology_batch_jit(ext, mem, intra, ext_frac, t_mask,
+                                         topo, ov, dest, sim=sim_p)
 
 
 def _sharding_note(out: dict, describe: dict) -> dict:

@@ -29,6 +29,7 @@ from repro.core.traffic.specs import (APP_NAMES, BurstySpec, HotspotSpec,
                                       ParsecSpec, PermutationSpec,
                                       TrafficSpec, UniformSpec, as_spec,
                                       permutation_destinations)
+from repro.runtime import spans
 
 
 def _lognormal_jitter(key: jax.Array, shape, cv: float) -> jax.Array:
@@ -154,6 +155,7 @@ def _generate_jit(spec: TrafficSpec, key: jax.Array,
     return _generate(spec, key, cfg)
 
 
+@spans.root("traffic.generate")
 def generate(spec, key: jax.Array, cfg: NetworkConfig = NETWORK, *,
              jit: bool = True, dest: bool = False) -> dict:
     """Generate one trace from a spec (or PARSEC app name) and a PRNG key.

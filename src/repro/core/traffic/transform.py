@@ -13,6 +13,8 @@ import jax.core as jax_core
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import spans
+
 # The array keys every trace must carry (plus the "app" label, the ragged-T
 # "t_mask", and the optional "dest" destination matrix). "dest" is [C, C] and
 # time-free: it must never be sliced/padded along T (with C == T the shape
@@ -57,42 +59,46 @@ def validate_trace(trace, who: str = "trace") -> dict:
     # Tracers (trace construction inside jit/vmap) have no values to check
     # and skip; concrete arrays (the common host-side path) are cheap to
     # scan once at the boundary.
-    for k in TRACE_KEYS:
-        v = trace[k]
-        if isinstance(v, jax_core.Tracer):
-            continue
-        arr = np.asarray(v)
-        if not np.issubdtype(arr.dtype, np.number):
-            raise ValueError(
-                f"{who}[{k!r}] must be numeric, got dtype {arr.dtype}")
-        if np.isnan(arr).any():
-            raise ValueError(
-                f"{who}[{k!r}] contains NaN — injected loads must be "
-                f"finite (the compiled scan would silently propagate "
-                f"NaN into every summary)")
-        if (arr < 0).any():
-            raise ValueError(
-                f"{who}[{k!r}] contains negative values (min "
-                f"{float(arr.min()):g}) — loads are non-negative "
-                f"flit rates")
-    d = trace.get("dest")
-    if d is not None and not isinstance(d, jax_core.Tracer):
-        arr = np.asarray(d)
-        c = int(np.shape(np.asarray(trace["ext_load"]))[-1]) \
-            if not isinstance(trace["ext_load"], jax_core.Tracer) else None
-        # Stacked batches (stack_traces) carry one leading [K] axis; the
-        # trailing two dims must still be square and match the chiplet axis.
-        if arr.ndim not in (2, 3) or arr.shape[-2] != arr.shape[-1] \
-                or (c is not None and arr.shape[-1] != c):
-            raise ValueError(
-                f"{who}['dest'] must be a square [C, C] destination matrix "
-                f"(optionally with one leading batch axis) matching the "
-                f"trace's chiplet axis"
-                f"{'' if c is None else f' (C={c})'}, got shape {arr.shape}")
-        if not np.isfinite(arr).all() or (arr < 0).any():
-            raise ValueError(
-                f"{who}['dest'] must be finite and non-negative (a "
-                f"row-stochastic destination distribution)")
+    with spans.span("traffic.validate"):
+        for k in TRACE_KEYS:
+            v = trace[k]
+            if isinstance(v, jax_core.Tracer):
+                continue
+            arr = np.asarray(v)
+            if not np.issubdtype(arr.dtype, np.number):
+                raise ValueError(
+                    f"{who}[{k!r}] must be numeric, got dtype {arr.dtype}")
+            if np.isnan(arr).any():
+                raise ValueError(
+                    f"{who}[{k!r}] contains NaN — injected loads must be "
+                    f"finite (the compiled scan would silently propagate "
+                    f"NaN into every summary)")
+            if (arr < 0).any():
+                raise ValueError(
+                    f"{who}[{k!r}] contains negative values (min "
+                    f"{float(arr.min()):g}) — loads are non-negative "
+                    f"flit rates")
+        d = trace.get("dest")
+        if d is not None and not isinstance(d, jax_core.Tracer):
+            arr = np.asarray(d)
+            ext = trace["ext_load"]
+            c = None if isinstance(ext, jax_core.Tracer) \
+                else int(np.shape(np.asarray(ext))[-1])
+            # Stacked batches (stack_traces) carry one leading [K] axis;
+            # the trailing two dims must still be square and match the
+            # chiplet axis.
+            if arr.ndim not in (2, 3) or arr.shape[-2] != arr.shape[-1] \
+                    or (c is not None and arr.shape[-1] != c):
+                raise ValueError(
+                    f"{who}['dest'] must be a square [C, C] destination "
+                    f"matrix (optionally with one leading batch axis) "
+                    f"matching the trace's chiplet axis"
+                    f"{'' if c is None else f' (C={c})'}, got shape "
+                    f"{arr.shape}")
+            if not np.isfinite(arr).all() or (arr < 0).any():
+                raise ValueError(
+                    f"{who}['dest'] must be finite and non-negative (a "
+                    f"row-stochastic destination distribution)")
     return trace
 
 

@@ -47,6 +47,7 @@ by every lane: all sessions experience the same interposer each tick.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from collections import Counter
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -58,11 +59,13 @@ import numpy as np
 from repro.core.selection import normalize_placement, resolve_gateway_positions
 from repro.core.simulator import (SimConfig, SimSession, init_session_states,
                                   selection_tables_jax, session_tick)
+from repro.runtime import spans
 from repro.serve import policies as P
 from repro.serve.policies import ServerPolicy
 from repro.serve.resilience import (DegradationDetector, ResiliencePolicy,
                                     plan_replacement)
-from repro.serve.scheduler import AdmissionQueue, ServeSession, SessionRequest
+from repro.serve.scheduler import (AdmissionQueue, ServeSession,
+                                   SessionRequest, new_session_id)
 
 _COUNTER_KEYS = (
     "submitted", "admitted", "completed", "shed_queue_full", "shed_memory",
@@ -152,6 +155,12 @@ class SessionServer:
         """
         if isinstance(req, dict):
             req = SessionRequest(trace=req)
+        if req.session_id is None:
+            req = dataclasses.replace(req, session_id=new_session_id())
+        with spans.root("serve.submit", session=req.session_id):
+            return self._submit(req)
+
+    def _submit(self, req: SessionRequest) -> dict:
         sess = ServeSession(req, self.policy, self.sim.cfg.n_chiplets,
                             self.tick_count)
         self.counters["submitted"] += 1
@@ -179,12 +188,19 @@ class SessionServer:
     def tick(self) -> dict:
         """One server tick: expire -> evict -> admit -> pack -> dispatch
         (coalesced when degraded) -> retry/complete -> heal. Never raises
-        for per-session conditions — they terminate via the taxonomy."""
+        for per-session conditions — they terminate via the taxonomy.
+        Each phase is a span (`repro.runtime.spans`) carrying the tick."""
         now = self.tick_count
-        self._expire_deadlines(now)
-        self._complete_drained(now)
-        self._evict_idle(now)
-        admitted = self._admit(now)
+        with spans.root("serve.tick", tick=now):
+            return self._tick(now)
+
+    def _tick(self, now: int) -> dict:
+        with spans.span("serve.housekeep"):
+            self._expire_deadlines(now)
+            self._complete_drained(now)
+            self._evict_idle(now)
+        with spans.span("serve.admit"):
+            admitted = self._admit(now)
         self._update_degraded()
         reps = self.policy.degrade_coalesce if self._degraded else 1
         served_lanes = 0
@@ -198,7 +214,8 @@ class SessionServer:
             # still dispatch exactly once per tick.
             dispatched = 0
             for want_dest in (False, True):
-                packed = self._pack(now, want_dest=want_dest)
+                with spans.span("serve.pack"):
+                    packed = self._pack(now, want_dest=want_dest)
                 if packed is None:
                     continue
                 dispatched += 1
@@ -210,7 +227,8 @@ class SessionServer:
                 break
             if rep > 0:
                 self.counters["coalesced_dispatches"] += 1
-        det = self._observe(lat_sum, valid_sum, served_lanes)
+        with spans.span("serve.observe"):
+            det = self._observe(lat_sum, valid_sum, served_lanes)
         self.tick_count += 1
         event = {"tick": now, "admitted": admitted,
                  "in_flight": self.sessions_in_flight,
@@ -443,55 +461,57 @@ class SessionServer:
         """One batched step + per-lane outcome handling. Returns the
         (latency sum, valid-interval sum, lanes served) telemetry."""
         batch, ready = packed["batch"], packed["ready"]
-        frame = self._tick_frame()
-        old_states = self._states          # kept for lane rollback: the
-        t0 = time.perf_counter()           # tick never donates its carry
-        new_states, recs, sums = session_tick(
-            old_states, batch, self._tables, self.sim, frame=frame)
-        jax.block_until_ready(sums)
-        self._dispatch_wall_s.append(time.perf_counter() - t0)
-        self.counters["dispatches"] += 1
-        self.hw_intervals += self.policy.chunk_intervals
-
-        host_sums = {k: np.asarray(v) for k, v in sums.items()}
-        keep = np.ones((self.policy.lanes,), bool)
-        lat_sum, valid_sum, served = 0.0, 0.0, 0
-        for lane in ready:
-            sess = self._lanes[lane]
-            lane_sums = {k: sums[k][lane] for k in sums}
-            failed = any(not np.isfinite(host_sums[k][lane])
-                         for k in host_sums)
-            if self.step_fault_hook is not None \
-                    and self.step_fault_hook(now, sess):
-                failed = True
-            if failed:
-                keep[lane] = False           # roll this lane's carry back
-                self.counters["retries"] += 1
-                if not sess.fail(now, self.policy):
-                    self._free_lane(sess, P.RETRY_EXHAUSTED, now)
-                continue
-            sess.advance(
-                lane_sums, now, self.placement, frame,
-                records={k: recs[k][lane] for k in recs}
-                if self.policy.keep_records else None,
-                keep_records=self.policy.keep_records)
-            self.counters["served_chunks"] += 1
-            lat_sum += float(host_sums["latency"][lane])
-            valid_sum += float(host_sums["valid_intervals"][lane])
-            served += 1
-            if sess.closed and not sess.pending:
-                self._free_lane(sess, P.COMPLETED, now)
-        if served:
-            self._demand_sample(batch, ready)
-        if keep.all():
-            self._states = new_states
-        else:
-            k = jnp.asarray(keep)
-            self._states = jax.tree.map(
-                lambda nb, ob: jnp.where(
-                    k.reshape((k.shape[0],) + (1,) * (nb.ndim - 1)), nb, ob),
-                new_states, old_states)
-        return lat_sum, valid_sum, served
+        with spans.span("serve.dispatch"):
+            frame = self._tick_frame()
+            old_states = self._states      # kept for lane rollback: the
+            t0 = time.perf_counter()       # tick never donates its carry
+            new_states, recs, sums = session_tick(
+                old_states, batch, self._tables, self.sim, frame=frame)
+            jax.block_until_ready(sums)
+            self._dispatch_wall_s.append(time.perf_counter() - t0)
+            self.counters["dispatches"] += 1
+            self.hw_intervals += self.policy.chunk_intervals
+        with spans.span("serve.outcome"):
+            host_sums = {k: np.asarray(v) for k, v in sums.items()}
+            keep = np.ones((self.policy.lanes,), bool)
+            lat_sum, valid_sum, served = 0.0, 0.0, 0
+            for lane in ready:
+                sess = self._lanes[lane]
+                lane_sums = {k: sums[k][lane] for k in sums}
+                failed = any(not np.isfinite(host_sums[k][lane])
+                             for k in host_sums)
+                if self.step_fault_hook is not None \
+                        and self.step_fault_hook(now, sess):
+                    failed = True
+                if failed:
+                    keep[lane] = False       # roll this lane's carry back
+                    self.counters["retries"] += 1
+                    if not sess.fail(now, self.policy):
+                        self._free_lane(sess, P.RETRY_EXHAUSTED, now)
+                    continue
+                sess.advance(
+                    lane_sums, now, self.placement, frame,
+                    records={k: recs[k][lane] for k in recs}
+                    if self.policy.keep_records else None,
+                    keep_records=self.policy.keep_records)
+                self.counters["served_chunks"] += 1
+                lat_sum += float(host_sums["latency"][lane])
+                valid_sum += float(host_sums["valid_intervals"][lane])
+                served += 1
+                if sess.closed and not sess.pending:
+                    self._free_lane(sess, P.COMPLETED, now)
+            if served:
+                self._demand_sample(batch, ready)
+            if keep.all():
+                self._states = new_states
+            else:
+                k = jnp.asarray(keep)
+                self._states = jax.tree.map(
+                    lambda nb, ob: jnp.where(
+                        k.reshape((k.shape[0],) + (1,) * (nb.ndim - 1)),
+                        nb, ob),
+                    new_states, old_states)
+            return lat_sum, valid_sum, served
 
     def _demand_sample(self, batch: dict, ready: List[int]) -> None:
         """Mean served-lane demand: the clean chunk re-placement candidates

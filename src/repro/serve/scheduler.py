@@ -39,6 +39,11 @@ from repro.serve.policies import (ACCEPT, PRIORITY_CLASSES,
 _session_counter = itertools.count()
 
 
+def new_session_id() -> str:
+    """A fresh per-process session id, `s<n>`."""
+    return f"s{next(_session_counter)}"
+
+
 @dataclasses.dataclass
 class SessionRequest:
     """A client submission. `trace` None opens a stream (feed chunks later
@@ -70,7 +75,7 @@ class ServeSession:
 
     def __init__(self, req: SessionRequest, policy: ServerPolicy,
                  n_chiplets: int, now: int):
-        self.id = req.session_id or f"s{next(_session_counter)}"
+        self.id = req.session_id or new_session_id()
         self.priority = req.priority
         self.submitted_tick = now
         dl = req.deadline_ticks if req.deadline_ticks is not None \
