@@ -1805,8 +1805,9 @@ def session_tick(states: SimState, batch: dict, tables: dict,
 def session_sums_zero() -> dict:
     """The additive identity of `_record_sums` totals (a session that has
     served nothing yet): partial summaries of never-served sessions come
-    out well-formed instead of raising."""
-    return {k: jnp.float32(0.0)
+    out well-formed instead of raising. Host `np.float32` zeros: a served
+    session's sums accumulate on the host in float32."""
+    return {k: np.float32(0.0)
             for k in ("latency", "power_mw", "energy", "gateways",
                       "wavelengths", "saturated", "reconfig_nj",
                       "valid_intervals")}

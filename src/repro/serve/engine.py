@@ -57,8 +57,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.selection import normalize_placement, resolve_gateway_positions
-from repro.core.simulator import (SimConfig, SimSession, init_session_states,
-                                  selection_tables_jax, session_tick)
+from repro.core.simulator import (SimConfig, SimSession, SimState,
+                                  init_session_states, selection_tables_jax,
+                                  session_tick)
 from repro.runtime import spans
 from repro.serve import policies as P
 from repro.serve.policies import ServerPolicy
@@ -104,8 +105,10 @@ class SessionServer:
         self.placement = normalize_placement(
             resolve_gateway_positions(sim.cfg), sim.cfg)
         self._tables = selection_tables_jax(sim.cfg)
-        self._states = init_session_states(sim, policy.lanes)
-        self._fresh = init_session_states(sim, 1)
+        # Every row of `_fresh` is a standalone session's initial carry;
+        # admission copies rows of it into `_states` (`_select_lanes`).
+        self._fresh = init_session_states(sim, policy.lanes)
+        self._states = self._fresh
         self._lanes: List[Optional[ServeSession]] = [None] * policy.lanes
         self.queue = AdmissionQueue(policy)
         self.sessions: Dict[str, ServeSession] = {}
@@ -360,7 +363,7 @@ class SessionServer:
                 self._free_lane(sess, P.IDLE_EVICTED, now)
 
     def _admit(self, now: int) -> int:
-        admitted = 0
+        fill = np.zeros((self.policy.lanes,), bool)
         for lane, occupant in enumerate(self._lanes):
             if occupant is not None:
                 continue
@@ -373,12 +376,14 @@ class SessionServer:
             sess.placement_at_admit = self.placement
             sess.last_progress_tick = now
             self._lanes[lane] = sess
-            # Fresh lane carry: row `lane` becomes a standalone session's
-            # initial state, so the lane replays `SimSession.init` exactly.
-            self._states = jax.tree.map(
-                lambda b, f: b.at[lane].set(f[0]), self._states, self._fresh)
-            self.counters["admitted"] += 1
-            admitted += 1
+            fill[lane] = True
+        admitted = int(fill.sum())
+        self.counters["admitted"] += admitted
+        if admitted:
+            # Fresh lane carries: each filled row becomes a standalone
+            # session's initial state, so the lane replays `SimSession.init`
+            # exactly.
+            self._states = _select_lanes(fill, self._fresh, self._states)
         return admitted
 
     def _update_degraded(self) -> None:
@@ -472,19 +477,19 @@ class SessionServer:
             self.counters["dispatches"] += 1
             self.hw_intervals += self.policy.chunk_intervals
         with spans.span("serve.outcome"):
-            host_sums = {k: np.asarray(v) for k, v in sums.items()}
-            keep = np.ones((self.policy.lanes,), bool)
+            # One transfer; every per-lane value below is a host float32.
+            host_sums = jax.device_get(sums)
+            rollback = np.zeros((self.policy.lanes,), bool)
             lat_sum, valid_sum, served = 0.0, 0.0, 0
             for lane in ready:
                 sess = self._lanes[lane]
-                lane_sums = {k: sums[k][lane] for k in sums}
-                failed = any(not np.isfinite(host_sums[k][lane])
-                             for k in host_sums)
+                lane_sums = {k: v[lane] for k, v in host_sums.items()}
+                failed = not all(np.isfinite(v) for v in lane_sums.values())
                 if self.step_fault_hook is not None \
                         and self.step_fault_hook(now, sess):
                     failed = True
                 if failed:
-                    keep[lane] = False       # roll this lane's carry back
+                    rollback[lane] = True    # roll this lane's carry back
                     self.counters["retries"] += 1
                     if not sess.fail(now, self.policy):
                         self._free_lane(sess, P.RETRY_EXHAUSTED, now)
@@ -495,22 +500,15 @@ class SessionServer:
                     if self.policy.keep_records else None,
                     keep_records=self.policy.keep_records)
                 self.counters["served_chunks"] += 1
-                lat_sum += float(host_sums["latency"][lane])
-                valid_sum += float(host_sums["valid_intervals"][lane])
+                lat_sum += float(lane_sums["latency"])
+                valid_sum += float(lane_sums["valid_intervals"])
                 served += 1
                 if sess.closed and not sess.pending:
                     self._free_lane(sess, P.COMPLETED, now)
             if served:
                 self._demand_sample(batch, ready)
-            if keep.all():
-                self._states = new_states
-            else:
-                k = jnp.asarray(keep)
-                self._states = jax.tree.map(
-                    lambda nb, ob: jnp.where(
-                        k.reshape((k.shape[0],) + (1,) * (nb.ndim - 1)),
-                        nb, ob),
-                    new_states, old_states)
+            self._states = _select_lanes(rollback, old_states, new_states) \
+                if rollback.any() else new_states
             return lat_sum, valid_sum, served
 
     def _demand_sample(self, batch: dict, ready: List[int]) -> None:
@@ -557,6 +555,16 @@ class SessionServer:
                 ("old_placement", "new_placement", "blocked_positions",
                  "search_best_score", "moved_gateways", "pcm_nj",
                  "stall_cycles")}
+
+
+@jax.jit
+def _select_lanes(take, src: SimState, dst: SimState) -> SimState:
+    """Row k of `src` where `take[k]`, else row k of `dst`, on every leaf
+    of two lane-stacked carries: admission's fresh rows and the retry
+    rollback in one dispatch, one executable per (lanes, state) shape."""
+    return jax.tree.map(
+        lambda a, b: jnp.where(take.reshape((-1,) + (1,) * (b.ndim - 1)),
+                               a, b), src, dst)
 
 
 def replay_standalone(sim: SimConfig, sess: ServeSession) -> dict:

@@ -8,13 +8,14 @@ the engine owns the packed dispatch):
     `SessionServer.feed`), plus a priority class and an optional
     deadline.
   * `ServeSession` — one admitted-or-queued session's state machine:
-    pending padded chunks, accumulated mask-correct sums, retry/backoff
-    state, a served log (chunk + placement + fault frame per successful
-    step) that lets `replay_standalone` re-run the session bit-exactly
-    through a standalone `SimSession`, and a `summary()` that is
-    well-formed at EVERY point of the lifecycle — including terminated
-    mid-retry or expired before serving anything (valid-intervals-only
-    reductions; zero served intervals means zero means, never a raise).
+    pending padded chunks, accumulated mask-correct sums (host float32
+    scalars), retry/backoff state, a served log (chunk + placement +
+    fault frame per successful step) that lets `replay_standalone`
+    re-run the session bit-exactly through a standalone `SimSession`,
+    and a `summary()` that is well-formed at EVERY point of the
+    lifecycle — including terminated mid-retry or expired before serving
+    anything (valid-intervals-only reductions; zero served intervals
+    means zero means, never a raise).
   * `AdmissionQueue` — the bounded priority queue with the backpressure
     and shedding policy: accept / throttle by depth, shed by capacity or
     queued-interval memory budget, premium displacement of queued lower
@@ -26,7 +27,6 @@ import dataclasses
 import itertools
 from typing import Dict, List, Optional, Tuple
 
-import jax
 import numpy as np
 
 from repro.core.simulator import session_sums_zero, summary_from_sums
@@ -71,6 +71,8 @@ class ServeSession:
     same mask-correct sufficient statistics a standalone `SimSession`
     carries, starting from the additive identity, so `summary()` is
     always well-formed — mid-retry, expired in the queue, or complete.
+    The sums live on the host as `np.float32` scalars and add in float32,
+    which rounds like the standalone session's device adds.
     """
 
     def __init__(self, req: SessionRequest, policy: ServerPolicy,
@@ -95,7 +97,7 @@ class ServeSession:
         self.placement_at_admit = None
         self.admitted_tick: Optional[int] = None
         self.terminated_tick: Optional[int] = None
-        self.sums: Dict[str, object] = session_sums_zero()
+        self.sums: Dict[str, np.float32] = session_sums_zero()
         self.retries = 0
         self.backoff_until = now
         self.last_progress_tick = now
@@ -150,10 +152,10 @@ class ServeSession:
 
     def advance(self, sums, now: int, placement, frame, records=None,
                 keep_records: bool = False) -> None:
-        """One chunk served successfully: fold its sums, log the replay
-        entry, reset the retry ladder."""
+        """One chunk served successfully: fold its sums (host `np.float32`
+        per key), log the replay entry, reset the retry ladder."""
         chunk = self.pending.pop(0)
-        self.sums = jax.tree.map(lambda a, b: a + b, self.sums, sums)
+        self.sums = {k: v + sums[k] for k, v in self.sums.items()}
         self.served_log.append(
             {"chunk": chunk, "placement": placement, "frame": frame})
         if keep_records and records is not None:

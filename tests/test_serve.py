@@ -43,7 +43,8 @@ from repro.core.simulator import (Arch, SimConfig, SimSession,
                                   reset_engine_stats, selection_tables_jax,
                                   session_tick)
 from repro.serve import policies as P
-from repro.serve.engine import SessionServer, replay_standalone
+from repro.serve.engine import (SessionServer, _select_lanes,
+                                replay_standalone)
 from repro.serve.policies import ServerPolicy
 from repro.serve.resilience import DegradationDetector, ResiliencePolicy
 from repro.serve.scheduler import SessionRequest
@@ -181,6 +182,75 @@ def test_server_one_executable_across_ticks_and_replay_parity():
     assert engine_stats()["simulate_traces"] <= 1, engine_stats()
     assert len(server.completed) == 7
     _assert_replay_parity(sim, server)
+
+
+@pytest.mark.parametrize("admit", [(), (1,), (0, 1, 2)],
+                         ids=["none", "one", "all"])
+def test_masked_reset_matches_per_lane_set(admit):
+    """Admission's one masked select gives, leaf for leaf and bit for bit,
+    what one `.at[lane].set(fresh[0])` per admitted lane gave: admitted
+    rows equal the fresh state, every other row is untouched."""
+    sim = _sim()
+    B, T = 3, 6
+    trs = [_tr(i, T) for i in range(B)]
+    batch = {
+        "ext_load": np.stack([np.asarray(t["ext_load"]) for t in trs]),
+        "mem_load": np.stack([np.asarray(t["mem_load"]) for t in trs]),
+        "int_load": np.stack([np.asarray(t["int_load"]) for t in trs]),
+        "ext_frac": np.stack([np.float32(t["ext_frac"]) for t in trs]),
+        "t_mask": np.ones((B, T), np.float32),
+    }
+    fresh = init_session_states(sim, B)
+    states, _, _ = session_tick(fresh, batch, selection_tables_jax(sim.cfg),
+                                sim)
+    take = np.zeros((B,), bool)
+    take[list(admit)] = True
+    got = _select_lanes(take, fresh, states)
+    want = states
+    for lane in admit:
+        want = jax.tree.map(lambda b, f, lane=lane: b.at[lane].set(f[0]),
+                            want, fresh)
+    for g, w, s, f in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                          jax.tree.leaves(states), jax.tree.leaves(fresh)):
+        g, w, s, f = (np.asarray(x) for x in (g, w, s, f))
+        assert g.dtype == w.dtype and np.array_equal(g, w, equal_nan=True)
+        for lane in range(B):
+            ref = f[0] if take[lane] else s[lane]
+            assert np.array_equal(g[lane], ref, equal_nan=True), lane
+
+
+def test_churning_drain_keeps_host_f32_sums_and_one_select_program():
+    """Admissions mid-serve plus one rolled-back retry: every session's
+    sums are host float32 scalars, every summary bit-matches its
+    standalone replay, and the lane select compiled at most once."""
+    sim = _sim()
+    fails = {"s_retry": 1}
+
+    def hook(tick, sess):
+        if tick >= 1 and fails.get(sess.id, 0) > 0:
+            fails[sess.id] -= 1
+            return True
+        return False
+
+    server = SessionServer(sim, ServerPolicy(
+        lanes=3, chunk_intervals=6, queue_capacity=10, retry_limit=2),
+        step_fault_hook=hook)
+    compiled0 = _select_lanes._cache_size()
+    server.submit(SessionRequest(trace=_tr(0, 14), session_id="s_retry"))
+    for i in range(1, 4):
+        server.submit(SessionRequest(trace=_tr(i, 5 + 4 * i)))
+    server.run(2)
+    for i in range(4, 7):                    # late arrivals mid-serve
+        server.submit(SessionRequest(trace=_tr(i, 7)))
+    server.drain()
+    assert server.metrics()["retries"] == 1
+    assert server.metrics()["admitted"] == 7
+    assert len(server.completed) == 7
+    for sess in server.completed:
+        assert all(type(v) is np.float32 for v in sess.sums.values()), \
+            {k: type(v) for k, v in sess.sums.items()}
+    _assert_replay_parity(sim, server)
+    assert _select_lanes._cache_size() - compiled0 <= 1
 
 
 # ---------------------------------------------------------------------------
