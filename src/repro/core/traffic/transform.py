@@ -10,6 +10,7 @@ latency/power/energy reduction (see simulator._simulate_impl).
 from __future__ import annotations
 
 import jax.core as jax_core
+import jax.dtypes as jax_dtypes
 import jax.numpy as jnp
 import numpy as np
 
@@ -130,13 +131,31 @@ def slice_trace(trace: dict, n_chiplets: int) -> dict:
     return out
 
 
+def _xp(a):
+    """The array module a transform keeps `a` in: numpy for host arrays
+    and numpy scalars, `jax.numpy` for everything else (device arrays,
+    tracers, Python values)."""
+    return np if isinstance(a, (np.ndarray, np.generic)) else jnp
+
+
+def _as_array(a, dtype=None):
+    """`a` as an array of its own kind, in the dtype `jnp.asarray` gives
+    (a host float64 comes out float32, as it would on the device)."""
+    xp = _xp(a)
+    if dtype is None and xp is np:
+        dtype = jax_dtypes.canonicalize_dtype(np.result_type(a))
+    return xp.asarray(a, dtype)
+
+
 def pad_trace(trace: dict, n_intervals: int) -> dict:
     """Zero-pad a trace's time axis to `n_intervals`, adding a `t_mask`.
 
     Padded tail intervals inject zero traffic and are masked out of every
     engine reduction, so a padded trace simulates identically to the
     original (the ragged-batching invariant, pinned per-arch in tests).
-    Already-padded traces extend their existing mask.
+    Already-padded traces extend their existing mask. Each key keeps its
+    array kind: host (numpy) inputs pad on the host, device arrays and
+    tracers through `jax.numpy`; a missing mask follows `ext_load`.
     """
     validate_trace(trace)
     t = int(jnp.shape(trace["ext_load"])[0])
@@ -144,15 +163,17 @@ def pad_trace(trace: dict, n_intervals: int) -> dict:
         raise ValueError(f"cannot pad a {t}-interval trace down to "
                          f"{n_intervals} (use slice on the time axis "
                          f"explicitly instead)")
-    mask = jnp.asarray(trace.get("t_mask", jnp.ones((t,), jnp.float32)),
-                       jnp.float32)
+    mask = trace.get("t_mask")
+    if mask is None:
+        mask = _xp(trace["ext_load"]).ones((t,), np.float32)
+    mask = _as_array(mask, np.float32)
     pad = n_intervals - t
     if pad == 0:
         return dict(trace, t_mask=mask)
 
     def _pad_time(a):
-        a = jnp.asarray(a)
-        return jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        a = _as_array(a)
+        return _xp(a).pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
 
     out = dict(trace)
     for k in ("ext_load", "mem_load", "int_load"):
@@ -175,6 +196,8 @@ def chunk_trace(trace: dict, size: int, *, pad: bool = False):
 
     Every per-interval key — the core loads, `t_mask`, and any extra array
     whose leading axis is T — is sliced; everything else is carried whole.
+    Chunks keep the trace's array kind (see `pad_trace`): a host trace
+    chunks and pads on the host, with no device round trip.
     The streaming companion to `SimSession.step_chunk` and the chunk feed
     of the continuous-batching `SessionServer` (fixed-shape lanes).
     """

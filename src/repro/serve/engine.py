@@ -72,7 +72,11 @@ _COUNTER_KEYS = (
     "submitted", "admitted", "completed", "shed_queue_full", "shed_memory",
     "shed_priority", "displaced", "deadline_expired", "idle_evicted",
     "retries", "retry_exhausted", "dispatches", "coalesced_dispatches",
-    "served_chunks", "degraded_ticks", "heals")
+    "served_chunks", "degraded_ticks", "heals", "pack_device_reads")
+
+# The chunk keys `_pack` copies into a lane of the batch.
+_PACK_KEYS = ("ext_load", "mem_load", "int_load", "ext_frac", "t_mask",
+              "dest")
 
 
 class SessionServer:
@@ -433,6 +437,10 @@ class SessionServer:
             ch = sess.pending[0]
             if (ch.get("dest") is not None) != want_dest:
                 continue
+            # Chunks are host arrays (`ServeSession.feed`); count any that
+            # is still on the device, since reading it back blocks the tick.
+            self.counters["pack_device_reads"] += sum(
+                isinstance(ch.get(k), jax.Array) for k in _PACK_KEYS)
             ext[lane] = np.asarray(ch["ext_load"], np.float32)
             mem[lane] = np.asarray(ch["mem_load"], np.float32)
             intra[lane] = np.asarray(ch["int_load"], np.float32)

@@ -1,21 +1,22 @@
 """Host-side bookkeeping for the continuous-batching session server.
 
-Three small pieces, all pure Python (nothing here touches the device —
-the engine owns the packed dispatch):
+Three small pieces, all on the host (nothing here runs on the device —
+the engine owns the packed dispatch; a device-resident trace is copied to
+the host once, at `feed`):
 
   * `SessionRequest` — what a client submits: a whole trace (closed
     session), or nothing yet (an open stream fed incrementally with
     `SessionServer.feed`), plus a priority class and an optional
     deadline.
   * `ServeSession` — one admitted-or-queued session's state machine:
-    pending padded chunks, accumulated mask-correct sums (host float32
-    scalars), retry/backoff state, a served log (chunk + placement +
-    fault frame per successful step) that lets `replay_standalone`
-    re-run the session bit-exactly through a standalone `SimSession`,
-    and a `summary()` that is well-formed at EVERY point of the
-    lifecycle — including terminated mid-retry or expired before serving
-    anything (valid-intervals-only reductions; zero served intervals
-    means zero means, never a raise).
+    pending padded chunks (host arrays), accumulated mask-correct sums
+    (host float32 scalars), retry/backoff state, a served log (chunk +
+    placement + fault frame per successful step) that lets
+    `replay_standalone` re-run the session bit-exactly through a
+    standalone `SimSession`, and a `summary()` that is well-formed at
+    EVERY point of the lifecycle — including terminated mid-retry or
+    expired before serving anything (valid-intervals-only reductions;
+    zero served intervals means zero means, never a raise).
   * `AdmissionQueue` — the bounded priority queue with the backpressure
     and shedding policy: accept / throttle by depth, shed by capacity or
     queued-interval memory budget, premium displacement of queued lower
@@ -27,6 +28,7 @@ import dataclasses
 import itertools
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.simulator import session_sums_zero, summary_from_sums
@@ -106,8 +108,8 @@ class ServeSession:
 
     # -- input side ---------------------------------------------------------
     def feed(self, trace: dict) -> int:
-        """Append a trace's intervals as padded fixed-T chunks; returns the
-        number of chunks enqueued."""
+        """Append a trace's intervals as padded fixed-T host chunks;
+        returns the number of chunks enqueued."""
         if self.closed:
             raise ValueError(f"session {self.id} is closed to new input")
         validate_trace(trace, who=f"session {self.id} trace")
@@ -124,6 +126,12 @@ class ServeSession:
             raise ValueError(
                 f"session {self.id} trace has {c} chiplets, the server "
                 f"simulates {self._n_chiplets}")
+        # Chunks stay on the host until the tick packs them: a device trace
+        # comes over in one transfer, a host trace passes through as is.
+        on_device = {k: v for k, v in trace.items()
+                     if isinstance(v, jax.Array)}
+        if on_device:
+            trace = {**trace, **jax.device_get(on_device)}
         n = 0
         for ch in chunk_trace(trace, self._chunk_t, pad=True):
             self.pending.append(ch)

@@ -253,6 +253,76 @@ def test_churning_drain_keeps_host_f32_sums_and_one_select_program():
     assert _select_lanes._cache_size() - compiled0 <= 1
 
 
+def _host(tr: dict) -> dict:
+    """The trace a client holds on the host (numpy arrays, as submitted by
+    the benchmark's serve runner)."""
+    return {k: (v if isinstance(v, str) else np.asarray(v))
+            for k, v in tr.items()}
+
+
+def _assert_host_chunks(chunks):
+    for ch in chunks:
+        for k, v in ch.items():
+            assert not isinstance(v, jax.Array), (k, type(v))
+
+
+def test_chunks_stay_on_the_host_and_pack_reads_nothing_back():
+    """Device-resident and host traces both queue host chunks, serve
+    without a single device read in `_pack`, keep host chunks in their
+    replay log, and bit-match their standalone replays."""
+    sim = _sim()
+    server = SessionServer(sim, ServerPolicy(lanes=3, chunk_intervals=6,
+                                             queue_capacity=10))
+    # 7, 9, 14 and 17 intervals all end in a padded 6-interval chunk.
+    sids = []
+    for i, t in enumerate((7, 9, 14, 17, 12)):
+        tr = _tr(i, t)
+        sids.append(server.submit(SessionRequest(
+            trace=tr if i % 2 else _host(tr)))["session_id"])
+    for sid in sids:
+        _assert_host_chunks(server.sessions[sid].pending)
+    server.drain()
+    assert len(server.completed) == len(sids)
+    for sess in server.completed:
+        _assert_host_chunks(e["chunk"] for e in sess.served_log)
+    assert server.counters["pack_device_reads"] == 0
+    assert server.metrics()["pack_device_reads"] == 0
+    _assert_replay_parity(sim, server)
+
+
+def test_device_and_host_fed_sessions_serve_the_same_numbers():
+    """The same values reach the same executable: a session fed a device
+    trace and one fed its host copy give bit-identical summaries."""
+    sim = _sim()
+    server = SessionServer(sim, ServerPolicy(lanes=3, chunk_intervals=6,
+                                             queue_capacity=10))
+    tr = _tr(3, 11)
+    a = server.submit(SessionRequest(trace=tr))["session_id"]
+    b = server.submit(SessionRequest(trace=_host(tr)))["session_id"]
+    server.drain()
+    sa, sb = server.sessions[a].summary(), server.sessions[b].summary()
+    for k in PARITY_KEYS:
+        assert sa[k] == sb[k], k
+
+
+def test_pack_counts_chunk_arrays_left_on_the_device():
+    """`pack_device_reads` counts each chunk array `_pack` had to read back
+    from the device, so a zero reading means none was there."""
+    sim = _sim()
+    server = SessionServer(sim, ServerPolicy(lanes=3, chunk_intervals=6,
+                                             queue_capacity=10))
+    sid = server.submit(SessionRequest(trace=_tr(0, 12)))["session_id"]
+    sess = server.sessions[sid]
+    sess.pending[0] = {k: (v if isinstance(v, str) else jnp.asarray(v))
+                       for k, v in sess.pending[0].items()}
+    server.tick()
+    # ext_load, mem_load, int_load, ext_frac, t_mask of one chunk.
+    assert server.metrics()["pack_device_reads"] == 5
+    server.drain()
+    assert server.metrics()["pack_device_reads"] == 5
+    _assert_replay_parity(sim, server)
+
+
 # ---------------------------------------------------------------------------
 # Admission control: signals, shedding taxonomy, displacement, memory
 # ---------------------------------------------------------------------------

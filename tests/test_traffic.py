@@ -234,6 +234,41 @@ def test_pad_trace_and_length():
         traffic.pad_trace(tr, 4)
 
 
+_LOAD_KEYS = ("ext_load", "mem_load", "int_load", "t_mask")
+
+
+def _pad_and_chunk(tr):
+    return [traffic.pad_trace(tr, 16)] \
+        + list(traffic.chunk_trace(tr, 8, pad=True))
+
+
+@pytest.mark.parametrize("kind", ["numpy", "jax", "jit"])
+def test_pad_and_chunk_keep_the_array_kind(kind):
+    """Host traces pad and chunk on the host (numpy float32, no device
+    round trip); device arrays and tracers keep the `jax.numpy` path.
+    The values and dtypes are the same either way."""
+    tr = traffic.generate_trace("dedup", 10, jax.random.PRNGKey(0))
+    want = _pad_and_chunk(tr)
+    arrays = {k: v for k, v in tr.items() if k != "app"}
+    if kind == "numpy":
+        got = _pad_and_chunk(dict(jax.device_get(arrays), app=tr["app"]))
+        expect_type = np.ndarray
+    elif kind == "jax":
+        got = _pad_and_chunk(tr)
+        expect_type = jax.Array
+    else:
+        got = jax.jit(_pad_and_chunk)(arrays)
+        expect_type = jax.Array
+    assert len(got) == len(want) == 3
+    for g, w in zip(got, want):
+        for k in _LOAD_KEYS:
+            assert isinstance(g[k], expect_type), (k, type(g[k]))
+            assert g[k].dtype == np.float32, (k, g[k].dtype)
+            np.testing.assert_array_equal(np.asarray(g[k]), np.asarray(w[k]))
+    np.testing.assert_array_equal(np.asarray(got[-1]["t_mask"]),
+                                  [1.0] * 2 + [0.0] * 6)
+
+
 def test_concat_preserves_t_mask():
     a = traffic.pad_trace(
         traffic.generate_trace("dedup", 6, jax.random.PRNGKey(0)), 8)
