@@ -1,4 +1,4 @@
-"""Epoch-level 2.5D network simulator (Level 1, DESIGN.md §3).
+"""Epoch-level 2.5D network simulator of the ReSiPI interposer (PAPER.md).
 
 Simulates the four compared interposer architectures (§4.1) over a traffic
 trace, one `lax.scan` step per reconfiguration interval:
@@ -11,8 +11,8 @@ trace, one `lax.scan` step per reconfiguration interval:
 Each step: traffic -> per-gateway load (selection tables) -> latency
 (noc.NocModel) -> power (photonics.interposer_power_mw) -> controller update.
 Energy is reported as power x mean-packet-latency (per-packet service-energy
-proxy; see EXPERIMENTS.md §Fig11 note) — consistent with the paper where the
--53% energy claim is the product of the -37% latency and -25% power claims.
+proxy) — consistent with the paper (PAPER.md), whose -53% energy claim is the
+product of its -37% latency and -25% power claims.
 
 Engine model (compile-once, batch-everywhere):
 
@@ -575,13 +575,10 @@ def clear_engine_caches() -> None:
     from repro.core.search import clear_search_caches
     from repro.core.traffic.dest import clear_destination_caches
 
-    for f in (_simulate_jit, _simulate_batch_jit, _sweep_jit,
-              _sweep_batch_jit, _sweep_topology_jit,
-              _sweep_topology_batch_jit, _sweep_workload_jit,
-              _sweep_workload_topo_jit, _session_chunk_jit,
-              _simulate_faults_jit, _simulate_batch_faults_jit,
-              _sweep_faults_jit, _session_chunk_faults_jit,
-              _session_tick_jit, _session_tick_faults_jit):
+    for f in (_simulate_jit, _sweep_jit, _sweep_batch_jit,
+              _sweep_topology_jit, _sweep_topology_batch_jit,
+              _sweep_workload_jit, _sweep_workload_topo_jit,
+              _session_chunk_jit, _session_tick_jit):
         f.clear_cache()
     clear_search_caches()
     clear_codesign_caches()
@@ -741,6 +738,18 @@ def check_placement_objective(objective: str) -> None:
             f"key: {sorted(SUMMARY_KEYS)})")
 
 
+def _masked_xs(ext: jax.Array, mem: jax.Array, intra: jax.Array,
+               ext_frac: jax.Array, t_mask: jax.Array,
+               faults: Optional[Tuple[jax.Array, ...]] = None) -> tuple:
+    """The per-interval scan inputs of one lane: (ext, mem, intra, ext_frac
+    broadcast over T, float t_mask), every load of a masked interval zeroed
+    so it injects nothing, then the fault frame when one rides."""
+    t_mask = t_mask.astype(jnp.float32)
+    xs = (ext * t_mask[:, None], mem * t_mask, intra * t_mask[:, None],
+          jnp.broadcast_to(ext_frac, mem.shape), t_mask)
+    return xs if faults is None else xs + tuple(faults)
+
+
 def _simulate_impl(ext: jax.Array, mem: jax.Array, intra: jax.Array,
                    ext_frac: jax.Array, t_mask: jax.Array, sim: SimConfig,
                    tables: dict, ov: Optional[Dict[str, jax.Array]] = None,
@@ -762,18 +771,14 @@ def _simulate_impl(ext: jax.Array, mem: jax.Array, intra: jax.Array,
     """
     sim = _apply_overrides(sim, ov)
     cfg = sim.cfg
-    t_mask = t_mask.astype(jnp.float32)
-    ext = ext * t_mask[:, None]
-    mem = mem * t_mask
-    intra = intra * t_mask[:, None]
+    xs = _masked_xs(ext, mem, intra, ext_frac, t_mask, faults)
     if topo is None:
         state0 = _initial_state(sim)
     else:
         valid = jnp.arange(cfg.n_chiplets) < topo["n_chiplets"]
         chip_mask = valid.astype(jnp.float32)
         topo = dict(topo, chip_mask=chip_mask)
-        ext = ext * chip_mask
-        intra = intra * chip_mask
+        xs = (xs[0] * chip_mask, xs[1], xs[2] * chip_mask) + xs[3:]
         if dest is not None:
             # Padded chiplet columns receive nothing and padded rows send
             # nothing; surviving rows re-normalize to row-stochastic with
@@ -796,13 +801,6 @@ def _simulate_impl(ext: jax.Array, mem: jax.Array, intra: jax.Array,
                                   jnp.asarray(w0).astype(jnp.int32), 0),
             prev_active=jnp.zeros((cfg.total_gateways,), bool))
 
-    xs = (ext, mem, intra, jnp.broadcast_to(ext_frac, mem.shape), t_mask)
-    if faults is not None:
-        if topo is not None:
-            raise ValueError("fault frames are not supported on the padded-"
-                             "topology paths (run faults on an unpadded "
-                             "config, or sweep them with sweep_faults)")
-        xs = xs + tuple(faults)
     _, recs = _scan_trace(state0, xs, sim, tables, topo,
                           faulted=faults is not None, dest=dest)
 
@@ -810,8 +808,19 @@ def _simulate_impl(ext: jax.Array, mem: jax.Array, intra: jax.Array,
     # per-chiplet average on padded-topology paths.
     n_lam = cfg.n_chiplets if topo is None \
         else jnp.maximum(jnp.sum(topo["chip_mask"]), 1.0)
-    summary = _summary_from_sums(_record_sums(recs, t_mask), n_lam)
+    summary = _summary_from_sums(_record_sums(recs, xs[4]), n_lam)
     return {"records": recs, "summary": summary}
+
+
+def _chunk_impl(state: SimState, ext, mem, intra, ext_frac, t_mask,
+                tables: dict, faults, dest, sim: SimConfig):
+    """One session chunk of one lane: scan from the carried `state`; returns
+    (new state, records, `_record_sums` totals). An all-masked lane injects
+    nothing, records zeros and freezes its carry."""
+    xs = _masked_xs(ext, mem, intra, ext_frac, t_mask, faults)
+    new_state, recs = _scan_trace(state, xs, sim, tables, None,
+                                  faulted=faults is not None, dest=dest)
+    return new_state, recs, _record_sums(recs, xs[4])
 
 
 def _trace_arrays(trace: dict) -> Tuple[jax.Array, ...]:
@@ -829,77 +838,58 @@ def _trace_arrays(trace: dict) -> Tuple[jax.Array, ...]:
             jnp.asarray(trace["ext_frac"]), t_mask, dest)
 
 
-def _trace_faults(trace: dict) -> Optional[Tuple[jax.Array, ...]]:
-    """The trace's fault frame as scan xs, or None when it carries none.
-
-    Returns (gw_ok [..., T, C, G], stuck_on [..., T, C, G], drift_db
-    [..., T]) in FAULT_KEYS order. A partial frame (some keys missing)
-    raises instead of silently simulating fault-free.
-    """
-    present = [k for k in FAULT_KEYS if k in trace]
-    if not present:
-        return None
-    missing = [k for k in FAULT_KEYS if k not in trace]
+def _fault_xs(frame: dict, n_intervals: int,
+              what: str) -> Tuple[jax.Array, ...]:
+    """A fault frame (`faults.compile_faults`, or K of them stacked) as
+    scan xs: (gw_ok [..., T, C, G], stuck_on [..., T, C, G], drift_db
+    [..., T]), float32. Raises when a key is missing or T is not
+    `n_intervals`, the length of `what`."""
+    missing = [k for k in FAULT_KEYS if k not in frame]
     if missing:
+        present = [k for k in FAULT_KEYS if k in frame]
         raise ValueError(
-            f"trace carries fault keys {present} but is missing {missing} "
-            f"— attach a complete frame with faults.attach_faults")
-    return tuple(jnp.asarray(trace[k], jnp.float32) for k in FAULT_KEYS)
+            f"the fault frame of {what} has {present} but is missing "
+            f"{missing} — build a complete one with faults.compile_faults "
+            f"or faults.no_faults (faults.attach_faults on a trace)")
+    flt = tuple(jnp.asarray(frame[k], jnp.float32) for k in FAULT_KEYS)
+    t = int(flt[0].shape[-3])
+    if t != n_intervals:
+        raise ValueError(
+            f"fault frames cover {t} intervals but {what} has "
+            f"{n_intervals} — compile them with n_intervals={n_intervals}")
+    return flt
 
+
+def _trace_faults(trace: dict) -> Optional[Tuple[jax.Array, ...]]:
+    """The trace's fault frame as scan xs (`_fault_xs`), or None when it
+    carries none. A partial frame raises."""
+    if not any(k in trace for k in FAULT_KEYS):
+        return None
+    return _fault_xs(trace, int(jnp.shape(trace["mem_load"])[-1]),
+                     "the trace")
+
+
+# One jitted program per entry point. `dest` and `faults` are trailing
+# optional pytrees: None adds no operand, so a clean call keeps its program;
+# present, each gets its own compiled program in the same jit cache.
 
 @functools.partial(jax.jit, static_argnames=("sim",))
-def _simulate_jit(ext, mem, intra, ext_frac, t_mask, tables, dest=None, *,
-                  sim: SimConfig):
+def _simulate_jit(ext, mem, intra, ext_frac, t_mask, tables, dest=None,
+                  faults=None, *, sim: SimConfig):
     return _simulate_impl(ext, mem, intra, ext_frac, t_mask, sim, tables,
-                          dest=dest)
+                          faults=faults, dest=dest)
 
 
 @functools.partial(jax.jit, static_argnames=("sim",))
-def _simulate_faults_jit(ext, mem, intra, ext_frac, t_mask, tables, flt,
-                         dest=None, *, sim: SimConfig):
-    """Fault twin of `_simulate_jit` (its own executable: the no-fault
-    entry points keep their exact shapes and caches)."""
-    return _simulate_impl(ext, mem, intra, ext_frac, t_mask, sim, tables,
-                          faults=flt, dest=dest)
-
-
-@functools.partial(jax.jit, static_argnames=("sim",))
-def _simulate_batch_faults_jit(ext, mem, intra, ext_frac, t_mask, tables,
-                               flt, dest=None, *, sim: SimConfig):
-    return jax.vmap(
-        lambda e, m, i, f, t, fl, d: _simulate_impl(e, m, i, f, t, sim,
-                                                    tables, faults=fl,
-                                                    dest=d)
-    )(ext, mem, intra, ext_frac, t_mask, flt, dest)
-
-
-@functools.partial(jax.jit, static_argnames=("sim",))
-def _sweep_faults_jit(ext, mem, intra, ext_frac, t_mask, tables, flt, ov,
-                      dest=None, *, sim: SimConfig):
-    """K fault frames (zipped with optional K runtime overrides) over one
-    trace — the fault grid vmaps exactly like every other sweep axis."""
+def _sweep_jit(ext, mem, intra, ext_frac, t_mask, tables, ov, dest=None,
+               faults=None, *, sim: SimConfig):
+    """K runtime-override points over one trace, zipped with K stacked
+    fault frames when `faults` is given (`sweep_faults`); with `ov={}` the
+    axis size comes from the frames alone."""
     return jax.vmap(
         lambda fl, o: _simulate_impl(ext, mem, intra, ext_frac, t_mask, sim,
                                      tables, o, faults=fl, dest=dest)
-    )(flt, ov)
-
-
-@functools.partial(jax.jit, static_argnames=("sim",))
-def _simulate_batch_jit(ext, mem, intra, ext_frac, t_mask, tables, dest=None,
-                        *, sim: SimConfig):
-    return jax.vmap(
-        lambda e, m, i, f, t, d: _simulate_impl(e, m, i, f, t, sim, tables,
-                                                dest=d)
-    )(ext, mem, intra, ext_frac, t_mask, dest)
-
-
-@functools.partial(jax.jit, static_argnames=("sim",))
-def _sweep_jit(ext, mem, intra, ext_frac, t_mask, tables, ov, dest=None, *,
-               sim: SimConfig):
-    return jax.vmap(
-        lambda o: _simulate_impl(ext, mem, intra, ext_frac, t_mask, sim,
-                                 tables, o, dest=dest)
-    )(ov)
+    )(faults, ov)
 
 
 @functools.partial(jax.jit, static_argnames=("sim",))
@@ -937,12 +927,13 @@ def _sweep_topology_batch_jit(ext, mem, intra, ext_frac, t_mask, topo, ov,
 
 @functools.partial(jax.jit, static_argnames=("sim",))
 def _sweep_workload_jit(ext, mem, intra, ext_frac, t_mask, tables, ov,
-                        dest=None, *, sim: SimConfig):
-    """K workload lanes zipped with K runtime-override lanes (one scan)."""
+                        dest=None, faults=None, *, sim: SimConfig):
+    """K workload lanes zipped with K runtime-override lanes (one scan);
+    `simulate_batch` runs it with `ov=None` and per-lane fault frames."""
     return jax.vmap(
-        lambda e, m, i, f, t, o, d: _simulate_impl(e, m, i, f, t, sim,
-                                                   tables, o, dest=d)
-    )(ext, mem, intra, ext_frac, t_mask, ov, dest)
+        lambda e, m, i, f, t, o, d, fl: _simulate_impl(
+            e, m, i, f, t, sim, tables, o, faults=fl, dest=d)
+    )(ext, mem, intra, ext_frac, t_mask, ov, dest, faults)
 
 
 @functools.partial(jax.jit, static_argnames=("sim",))
@@ -958,34 +949,18 @@ def _sweep_workload_topo_jit(ext, mem, intra, ext_frac, t_mask, topo, ov,
 
 @functools.partial(jax.jit, static_argnames=("sim",), donate_argnums=(0,))
 def _session_chunk_jit(state, ext, mem, intra, ext_frac, t_mask, tables,
-                       dest=None, *, sim: SimConfig):
-    """One streaming chunk: scan from the carried state, return the new
-    carry (donated — the old state's buffers are reused in place), the
-    chunk's records, and mask-correct running totals."""
-    t_mask = t_mask.astype(jnp.float32)
-    xs = (ext * t_mask[:, None], mem * t_mask, intra * t_mask[:, None],
-          jnp.broadcast_to(ext_frac, mem.shape), t_mask)
-    new_state, recs = _scan_trace(state, xs, sim, tables, None, dest=dest)
-    return new_state, recs, _record_sums(recs, t_mask)
-
-
-@functools.partial(jax.jit, static_argnames=("sim",), donate_argnums=(0,))
-def _session_chunk_faults_jit(state, ext, mem, intra, ext_frac, t_mask,
-                              tables, flt, dest=None, *, sim: SimConfig):
-    """Fault twin of `_session_chunk_jit`: the chunk's fault-frame slice
+                       dest=None, faults=None, *, sim: SimConfig):
+    """One streaming chunk: `_chunk_impl` with the carry donated (the old
+    state's buffers are reused in place). The chunk's fault-frame slice
     (aligned by chunk_trace, which slices FAULT_KEYS with the loads) rides
-    as extra scan xs; clean chunks keep their own executable."""
-    t_mask = t_mask.astype(jnp.float32)
-    xs = (ext * t_mask[:, None], mem * t_mask, intra * t_mask[:, None],
-          jnp.broadcast_to(ext_frac, mem.shape), t_mask) + tuple(flt)
-    new_state, recs = _scan_trace(state, xs, sim, tables, None, faulted=True,
-                                  dest=dest)
-    return new_state, recs, _record_sums(recs, t_mask)
+    as `faults`."""
+    return _chunk_impl(state, ext, mem, intra, ext_frac, t_mask, tables,
+                       faults, dest, sim)
 
 
 @functools.partial(jax.jit, static_argnames=("sim",))
 def _session_tick_jit(states, ext, mem, intra, ext_frac, t_mask, tables,
-                      dest=None, *, sim: SimConfig):
+                      dest=None, faults=None, *, sim: SimConfig):
     """One continuous-batching server tick: B session carries advance
     through B masked chunk scans as ONE vmapped executable.
 
@@ -993,32 +968,14 @@ def _session_tick_jit(states, ext, mem, intra, ext_frac, t_mask, tables,
     bit-transparent on CPU — pinned by tests/test_serve.py): a lane whose
     `t_mask` row is all zeros injects nothing, records zeros, and FREEZES
     its carry, so empty / backing-off / parked lanes ride along for free
-    and the executable's [B, T] shape never changes across ticks.
+    and the executable's [B, T] shape never changes across ticks. `dest`
+    is per lane; the fault frame lives on hardware time and is SHARED by
+    every lane (closed over, not vmapped).
     """
-    def one(st, e, m, i, f, t, d):
-        t = t.astype(jnp.float32)
-        xs = (e * t[:, None], m * t, i * t[:, None],
-              jnp.broadcast_to(f, m.shape), t)
-        new_state, recs = _scan_trace(st, xs, sim, tables, None, dest=d)
-        return new_state, recs, _record_sums(recs, t)
-    return jax.vmap(one)(states, ext, mem, intra, ext_frac, t_mask, dest)
-
-
-@functools.partial(jax.jit, static_argnames=("sim",))
-def _session_tick_faults_jit(states, ext, mem, intra, ext_frac, t_mask,
-                             tables, flt, dest=None, *, sim: SimConfig):
-    """Fault twin of `_session_tick_jit`: the tick's fault frame lives on
-    hardware time and is SHARED by every lane (closed over, not vmapped) —
-    all sessions experience the same interposer this tick. Its own
-    executable, so fault-free serving keeps the clean tick's cache."""
-    def one(st, e, m, i, f, t, d):
-        t = t.astype(jnp.float32)
-        xs = (e * t[:, None], m * t, i * t[:, None],
-              jnp.broadcast_to(f, m.shape), t) + tuple(flt)
-        new_state, recs = _scan_trace(st, xs, sim, tables, None,
-                                      faulted=True, dest=d)
-        return new_state, recs, _record_sums(recs, t)
-    return jax.vmap(one)(states, ext, mem, intra, ext_frac, t_mask, dest)
+    return jax.vmap(
+        lambda st, e, m, i, f, t, d: _chunk_impl(st, e, m, i, f, t, tables,
+                                                 faults, d, sim)
+    )(states, ext, mem, intra, ext_frac, t_mask, dest)
 
 
 # ---------------------------------------------------------------------------
@@ -1032,18 +989,19 @@ def simulate(trace: dict, sim: SimConfig) -> dict:
     equal config and trace shape re-traces nothing (engine_stats() shows the
     counter), and the selection tables are memoized per NetworkConfig.
 
-    A trace carrying a fault frame (faults.attach_faults) routes to the
-    fault twin of the scan automatically; traces without one never pay for
-    the fault arithmetic and keep their own executables.
+    A fault frame the trace carries (faults.attach_faults) reaches the scan
+    as an optional `faults` pytree: its own compiled program when present,
+    so traces without one never pay for the fault arithmetic.
     """
+    return _simulate_jit(*_simulate_operands(trace, sim), sim=sim)
+
+
+def _simulate_operands(trace: dict, sim: SimConfig) -> tuple:
+    """The operands `simulate` hands `_simulate_jit` (`simulate_batch`
+    builds them for a stacked batch; runtime.cache lowers the same)."""
     ext, mem, intra, ext_frac, t_mask, dest = _trace_arrays(trace)
-    flt = _trace_faults(trace)
-    if flt is not None:
-        return _simulate_faults_jit(ext, mem, intra, ext_frac, t_mask,
-                                    selection_tables_jax(sim.cfg), flt,
-                                    dest, sim=sim)
-    return _simulate_jit(ext, mem, intra, ext_frac, t_mask,
-                         selection_tables_jax(sim.cfg), dest, sim=sim)
+    return (ext, mem, intra, ext_frac, t_mask, selection_tables_jax(sim.cfg),
+            dest, _trace_faults(trace))
 
 
 def simulate_eager(trace: dict, sim: SimConfig) -> dict:
@@ -1133,14 +1091,10 @@ def simulate_batch(traces, sim: SimConfig) -> dict:
     """
     batch = stack_traces(traces, pad=True) \
         if isinstance(traces, (list, tuple)) else traces
-    ext, mem, intra, ext_frac, t_mask, dest = _trace_arrays(batch)
-    flt = _trace_faults(batch)
-    if flt is not None:
-        return _simulate_batch_faults_jit(ext, mem, intra, ext_frac, t_mask,
-                                          selection_tables_jax(sim.cfg),
-                                          flt, dest, sim=sim)
-    return _simulate_batch_jit(ext, mem, intra, ext_frac, t_mask,
-                               selection_tables_jax(sim.cfg), dest, sim=sim)
+    ext, mem, intra, ext_frac, t_mask, tables, dest, flt = \
+        _simulate_operands(batch, sim)
+    return _sweep_workload_jit(ext, mem, intra, ext_frac, t_mask, tables,
+                               None, dest, flt, sim=sim)
 
 
 def sweep(trace: dict, sim: SimConfig, **fields) -> dict:
@@ -1155,10 +1109,16 @@ def sweep(trace: dict, sim: SimConfig, **fields) -> dict:
     not on the grid *values*, so re-sweeping a same-sized grid elsewhere in
     the space is compile-free.
     """
+    return _sweep_jit(*_sweep_operands(trace, sim, fields), sim=sim)
+
+
+def _sweep_operands(trace: dict, sim: SimConfig, fields: dict) -> tuple:
+    """The operands `sweep` hands `_sweep_jit` (and `sweep_batch`
+    `_sweep_batch_jit`; runtime.cache lowers the same)."""
     ov = _check_sweep_fields(fields)
     ext, mem, intra, ext_frac, t_mask, dest = _trace_arrays(trace)
-    return _sweep_jit(ext, mem, intra, ext_frac, t_mask,
-                      selection_tables_jax(sim.cfg), ov, dest, sim=sim)
+    return (ext, mem, intra, ext_frac, t_mask, selection_tables_jax(sim.cfg),
+            ov, dest)
 
 
 def _check_sweep_fields(fields) -> Dict[str, jax.Array]:
@@ -1183,12 +1143,9 @@ def sweep_batch(traces, sim: SimConfig, **fields) -> dict:
     """
     batch = stack_traces(traces, pad=True) \
         if isinstance(traces, (list, tuple)) else traces
-    ov = _check_sweep_fields(fields)
-    ext, mem, intra, ext_frac, t_mask, dest = _trace_arrays(batch)
+    args = _sweep_operands(batch, sim, fields)
     with spans.span("sim.dispatch"):
-        return _sweep_batch_jit(ext, mem, intra, ext_frac, t_mask,
-                                selection_tables_jax(sim.cfg), ov, dest,
-                                sim=sim)
+        return _sweep_batch_jit(*args, sim=sim)
 
 
 def sweep_faults(trace: dict, sim: SimConfig, frames, **fields) -> dict:
@@ -1201,38 +1158,28 @@ def sweep_faults(trace: dict, sim: SimConfig, frames, **fields) -> dict:
     fault axis, so fault scenarios compose with every runtime-override
     sweep axis. Results carry a leading [K] axis; compilation caches on
     (trace shape, config, K, swept-field set), not on which faults fire.
+    The frames are `_sweep_jit`'s optional `faults` pytree: its own
+    compiled program when present.
     """
     if _trace_faults(trace) is not None:
         raise ValueError(
             "sweep_faults() takes the fault grid via `frames`; pass a clean "
             "trace (faults.strip_faults) instead of an attached one")
-    from repro.core.faults import stack_fault_frames as _stack
-    stacked = _stack(frames) if isinstance(frames, (list, tuple)) else frames
-    missing = [k for k in FAULT_KEYS if k not in stacked]
-    if missing:
-        raise ValueError(f"fault frames are missing keys {missing}")
-    flt = tuple(jnp.asarray(stacked[k], jnp.float32) for k in FAULT_KEYS)
-    k = int(flt[0].shape[0])
+    stacked = stack_fault_frames(frames) \
+        if isinstance(frames, (list, tuple)) else frames
     ext, mem, intra, ext_frac, t_mask, dest = _trace_arrays(trace)
-    t = int(jnp.shape(mem)[0])
-    if int(flt[0].shape[1]) != t:
+    flt = _fault_xs(stacked, int(jnp.shape(mem)[0]), "the trace")
+    k = int(flt[0].shape[0])
+    # Without fields the override pytree is empty: it has no mapped leaves,
+    # so the vmap axis size comes from the fault frames alone.
+    ov = _check_sweep_fields(fields) if fields else {}
+    k_ov = next(iter(ov.values())).shape[0] if ov else k
+    if k_ov != k:
         raise ValueError(
-            f"fault frames cover {int(flt[0].shape[1])} intervals but the "
-            f"trace has {t} — compile them with n_intervals={t}")
-    if fields:
-        ov = _check_sweep_fields(fields)
-        k_ov = next(iter(ov.values())).shape[0]
-        if k_ov != k:
-            raise ValueError(
-                f"swept fields have length {k_ov} but there are {k} fault "
-                f"frames — the axes zip lane-for-lane")
-    else:
-        # An empty override pytree has no mapped leaves; the vmap axis size
-        # comes from the fault frame alone.
-        ov = {}
-    return _sweep_faults_jit(ext, mem, intra, ext_frac, t_mask,
-                             selection_tables_jax(sim.cfg), flt, ov, dest,
-                             sim=sim)
+            f"swept fields have length {k_ov} but there are {k} fault "
+            f"frames — the axes zip lane-for-lane")
+    return _sweep_jit(ext, mem, intra, ext_frac, t_mask,
+                      selection_tables_jax(sim.cfg), ov, dest, flt, sim=sim)
 
 
 # ---------------------------------------------------------------------------
@@ -1406,10 +1353,17 @@ def sweep_topology(trace: dict, sim: SimConfig, **grids) -> dict:
     Controller gateway bounds are clamped per point to the topology's
     gateway count (see `topology_point_config`).
     """
+    args, sim_p = _sweep_topology_operands(trace, sim, grids)
+    return _sweep_topology_jit(*args, sim=sim_p)
+
+
+def _sweep_topology_operands(trace: dict, sim: SimConfig,
+                             grids: dict) -> Tuple[tuple, SimConfig]:
+    """(operands, padded static config) of the padded topology jits, for
+    one trace or a stacked batch (runtime.cache lowers the same)."""
     sim_p, topo, ov, c_max = _prepare_topology_sweep(sim, grids)
     ext, mem, intra, ext_frac, t_mask, dest = _topo_trace_arrays(trace, c_max)
-    return _sweep_topology_jit(ext, mem, intra, ext_frac, t_mask, topo, ov,
-                               dest, sim=sim_p)
+    return (ext, mem, intra, ext_frac, t_mask, topo, ov, dest), sim_p
 
 
 @spans.root("sim.sweep_topology_batch")
@@ -1426,11 +1380,9 @@ def sweep_topology_batch(traces, sim: SimConfig, *, devices=None,
         return shard_sweep(traces, sim, devices=devices, **grids)
     batch = stack_traces(traces, pad=True) \
         if isinstance(traces, (list, tuple)) else traces
-    sim_p, topo, ov, c_max = _prepare_topology_sweep(sim, grids)
-    ext, mem, intra, ext_frac, t_mask, dest = _topo_trace_arrays(batch, c_max)
+    args, sim_p = _sweep_topology_operands(batch, sim, grids)
     with spans.span("sim.dispatch"):
-        return _sweep_topology_batch_jit(ext, mem, intra, ext_frac, t_mask,
-                                         topo, ov, dest, sim=sim_p)
+        return _sweep_topology_batch_jit(*args, sim=sim_p)
 
 
 def _sharding_note(out: dict, describe: dict) -> dict:
@@ -1478,11 +1430,10 @@ def shard_sweep(traces, sim: SimConfig, *, devices=None, **grids) -> dict:
                 out["summary"]["mean_latency"]).shape[-1]),
             "pad_lanes": 0, "devices": 1, "processes": 1})
 
-    sim_p, topo, ov, c_max = _prepare_topology_sweep(sim, grids)
     batch = stack_traces(traces, pad=True) \
         if isinstance(traces, (list, tuple)) else traces
-    ext, mem, intra, ext_frac, t_mask, dest = _topo_trace_arrays(
-        batch, c_max)
+    (ext, mem, intra, ext_frac, t_mask, topo, ov, dest), sim_p = \
+        _sweep_topology_operands(batch, sim, grids)
 
     k = int(topo["n_chiplets"].shape[0])
     gs = GridSharding(k, devices=devices)
@@ -1573,15 +1524,12 @@ def sweep_workload(specs, sim: SimConfig, *, seed: int = 0, keys=None,
         traces = [traffic.generate(s, ky, gen_cfg, dest=dest)
                   for s, ky in zip(specs, keys)]
         batch = stack_traces(traces, pad=True)
-        sim_p, topo, ov, c_max = _prepare_topology_sweep(sim, grids)
-        ext, mem, intra, ext_frac, t_mask, dmat = _topo_trace_arrays(
-            batch, c_max)
+        args, sim_p = _sweep_topology_operands(batch, sim, grids)
         if devices is not None and len(devices) > 1:
             return _shard_workload(
-                (ext, mem, intra, ext_frac, t_mask, topo, ov, dmat),
-                devices, lambda a, _: _sweep_workload_topo_jit(*a, sim=sim_p))
-        return _sweep_workload_topo_jit(ext, mem, intra, ext_frac, t_mask,
-                                        topo, ov, dmat, sim=sim_p)
+                args, devices,
+                lambda a, _: _sweep_workload_topo_jit(*a, sim=sim_p))
+        return _sweep_workload_topo_jit(*args, sim=sim_p)
 
     unknown = set(grids) - set(SWEEPABLE_FIELDS)
     if unknown:
@@ -1638,7 +1586,8 @@ class SimSession:
     bit-match, and the running summary matches up to float re-association
     of the partial sums. Chunks of equal length share one compiled
     executable; `engine_stats()` shows one scan-body trace per chunk
-    shape.
+    shape. A chunk's fault frame (faults.attach_faults) is an optional
+    `faults` pytree: its own compiled program when present.
     """
 
     def __init__(self, sim: SimConfig, state: SimState, tables: dict):
@@ -1690,15 +1639,9 @@ class SimSession:
             raise ValueError(
                 f"step_chunk takes one unbatched trace chunk "
                 f"(ext_load [T, C]), got ext_load {ext.shape}")
-        flt = _trace_faults(chunk)
-        if flt is not None:
-            self._state, recs, sums = _session_chunk_faults_jit(
-                self._state, ext, mem, intra, ext_frac, t_mask,
-                self._tables, flt, dest, sim=self.sim)
-        else:
-            self._state, recs, sums = _session_chunk_jit(
-                self._state, ext, mem, intra, ext_frac, t_mask,
-                self._tables, dest, sim=self.sim)
+        self._state, recs, sums = _session_chunk_jit(
+            self._state, ext, mem, intra, ext_frac, t_mask, self._tables,
+            dest, _trace_faults(chunk), sim=self.sim)
         self._sums = sums if self._sums is None else jax.tree.map(
             lambda a, b: a + b, self._sums, sums)
         return {"records": recs,
@@ -1761,8 +1704,9 @@ def session_tick(states: SimState, batch: dict, tables: dict,
 
     `frame` (optional) is ONE fault frame (gw_ok [T, C, G] / stuck_on
     [T, C, G] / drift_db [T]) shared by every lane — faults live on
-    hardware time, not session time — routed to the fault twin so clean
-    ticks keep their own executable and exact numerics.
+    hardware time, not session time. It is an optional `faults` pytree:
+    its own compiled program when present, so clean ticks keep theirs and
+    their exact numerics.
 
     An optional `batch["dest"]` [B, C, C] (per-lane destination matrices,
     e.g. from `stack_traces` over `generate(..., dest=True)` chunks) routes
@@ -1773,6 +1717,14 @@ def session_tick(states: SimState, batch: dict, tables: dict,
     The carry is NOT donated: the caller may keep the previous states
     pytree to roll back lanes whose step failed (retry path).
     """
+    return _session_tick_jit(
+        *_session_tick_operands(states, batch, tables, frame), sim=sim)
+
+
+def _session_tick_operands(states: SimState, batch: dict, tables: dict,
+                           frame: Optional[dict] = None) -> tuple:
+    """The operands `session_tick` hands `_session_tick_jit` (runtime.cache
+    lowers the same)."""
     ext = jnp.asarray(batch["ext_load"])
     mem = jnp.asarray(batch["mem_load"])
     intra = jnp.asarray(batch["int_load"])
@@ -1785,21 +1737,9 @@ def session_tick(states: SimState, batch: dict, tables: dict,
             f"session_tick takes lane-stacked chunks (ext_load [B, T, C], "
             f"mem_load [B, T], t_mask [B, T]); got ext_load {ext.shape}, "
             f"mem_load {mem.shape}, t_mask {t_mask.shape}")
-    if frame is None:
-        return _session_tick_jit(states, ext, mem, intra, ext_frac, t_mask,
-                                 tables, dest, sim=sim)
-    missing = [k for k in FAULT_KEYS if k not in frame]
-    if missing:
-        raise ValueError(f"fault frame is missing {missing} "
-                         f"(build it with faults.compile_faults/no_faults)")
-    flt = tuple(jnp.asarray(frame[k], jnp.float32) for k in FAULT_KEYS)
-    if int(flt[0].shape[0]) != int(mem.shape[1]):
-        raise ValueError(
-            f"fault frame covers {int(flt[0].shape[0])} intervals but the "
-            f"tick chunk has {int(mem.shape[1])} — compile the frame at "
-            f"the server's chunk length")
-    return _session_tick_faults_jit(states, ext, mem, intra, ext_frac,
-                                    t_mask, tables, flt, dest, sim=sim)
+    flt = None if frame is None \
+        else _fault_xs(frame, int(mem.shape[1]), "the tick chunk")
+    return (states, ext, mem, intra, ext_frac, t_mask, tables, dest, flt)
 
 
 def session_sums_zero() -> dict:

@@ -160,50 +160,28 @@ def _param_key(kw: dict) -> tuple:
 
 
 def _builders():
-    """entry name -> (args_builder, jit_fn). The builder reproduces the
-    public entry point's preprocessing so the compiled call is fed
-    identically-shaped operands."""
-    from repro.core import simulator as S
+    """entry name -> (operands, jit_fn). `operands(*args, **kw)` takes the
+    public entry point's arguments and returns (jit operands, static jit
+    kwargs) from the simulator's own operand function, so the compiled call
+    is fed exactly what the public entry point feeds its jit."""
+    from repro.core import pareto, simulator as S
 
-    def b_simulate(trace, sim):
-        ext, mem, intra, ext_frac, t_mask, dest = S._trace_arrays(trace)
-        return (ext, mem, intra, ext_frac, t_mask,
-                S.selection_tables_jax(sim.cfg), dest)
+    def sweep_topology(trace, sim, **grids):
+        built, sim_p = S._sweep_topology_operands(trace, sim, grids)
+        return built, {"sim": sim_p}
 
-    def b_sweep(trace, sim, **fields):
-        ext, mem, intra, ext_frac, t_mask, dest = S._trace_arrays(trace)
-        import jax.numpy as jnp
-        ov = {f: jnp.asarray(v) for f, v in fields.items()}
-        return (ext, mem, intra, ext_frac, t_mask,
-                S.selection_tables_jax(sim.cfg), ov, dest)
-
-    def b_sweep_topology(trace, sim, **grids):
-        sim_p, topo, ov, c_max = S._prepare_topology_sweep(sim, grids)
-        ext, mem, intra, ext_frac, t_mask, dest = S._topo_trace_arrays(
-            trace, c_max)
-        return (ext, mem, intra, ext_frac, t_mask, topo, ov, dest), sim_p
-
-    def b_session_tick(states, batch, tables, sim):
-        import jax.numpy as jnp
-        dest = batch.get("dest")
-        return (states, jnp.asarray(batch["ext_load"]),
-                jnp.asarray(batch["mem_load"]),
-                jnp.asarray(batch["int_load"]),
-                jnp.asarray(batch["ext_frac"]),
-                jnp.asarray(batch["t_mask"], jnp.float32), tables,
-                None if dest is None else jnp.asarray(dest, jnp.float32))
-
-    def b_search(trace, sim, **kw):
-        from repro.core import pareto
-        built, statics, _info = pareto._codesign_operands(trace, sim, **kw)
-        return built, statics
-
-    from repro.core import pareto as _pareto
-    return {"simulate": (b_simulate, S._simulate_jit),
-            "sweep": (b_sweep, S._sweep_jit),
-            "sweep_topology": (b_sweep_topology, S._sweep_topology_jit),
-            "session_tick": (b_session_tick, S._session_tick_jit),
-            "search": (b_search, _pareto._codesign_jit)}
+    return {
+        "simulate": (lambda trace, sim: (S._simulate_operands(trace, sim),
+                                         {"sim": sim}), S._simulate_jit),
+        "sweep": (lambda trace, sim, **fields: (
+            S._sweep_operands(trace, sim, fields), {"sim": sim}),
+            S._sweep_jit),
+        "sweep_topology": (sweep_topology, S._sweep_topology_jit),
+        "session_tick": (lambda states, batch, tables, sim, frame=None: (
+            S._session_tick_operands(states, batch, tables, frame),
+            {"sim": sim}), S._session_tick_jit),
+        "search": (lambda trace, sim, **kw: pareto._codesign_operands(
+            trace, sim, **kw)[:2], pareto._codesign_jit)}
 
 
 def _persist_path(key: tuple) -> Optional[pathlib.Path]:
@@ -241,11 +219,11 @@ def aot_compile(entry: str, *args, **kw) -> AotEntry:
 
     Entries: "simulate" (trace, sim), "sweep" (trace, sim, **fields),
     "sweep_topology" (trace, sim, **grids), "session_tick" (states, batch,
-    tables, sim), "search" (trace, sim, **search_codesign kwargs — the
-    Pareto co-design dispatch, so a fleet worker's first `search_codesign`
-    skips tracing + XLA). Compiled executables are memoized on (entry, sim config,
-    grid values, input shapes/dtypes) — a second call with a same-shaped
-    trace returns the cached handle. Compiles go through the persistent
+    tables, sim[, frame]), "search" (trace, sim, **search_codesign kwargs —
+    the Pareto co-design dispatch, so a fleet worker's first
+    `search_codesign` skips tracing + XLA). Compiled executables are
+    memoized on (entry, sim config, grid values, input shapes/dtypes) — a
+    second call with a same-shaped trace returns the cached handle. Compiles go through the persistent
     cache when `enable_persistent_cache` is on, so AOT warmup in one
     process is compile-free in the next.
     """
@@ -254,30 +232,14 @@ def aot_compile(entry: str, *args, **kw) -> AotEntry:
         raise ValueError(f"unknown AOT entry point {entry!r} "
                          f"(choose from {AOT_ENTRY_POINTS})")
     build, jit_fn = builders[entry]
-
-    if entry == "sweep_topology":
-        trace, sim = args
-        built, sim_static = build(trace, sim, **kw)
-        lower_kw = {"sim": sim_static}
-        key = (entry, sim, _grid_key(kw), _shape_key(built))
-        rebuild = lambda tr, sm, **g: build(tr, sm, **g)[0]
-    elif entry == "search":
-        trace, sim = args
-        built, lower_kw = build(trace, sim, **kw)
-        key = (entry, sim, _param_key(kw), _shape_key(built))
-        rebuild = lambda tr, sm, **k: build(tr, sm, **k)[0]
-    elif entry == "session_tick":
-        states, batch, tables, sim = args
-        built = build(states, batch, tables, sim)
-        lower_kw = {"sim": sim}
-        key = (entry, sim, (), _shape_key(built))
-        rebuild = build
+    built, lower_kw = build(*args, **kw)
+    if entry == "session_tick":  # a fault frame is an operand, not a key
+        sim, params = args[3], ()
     else:
         sim = args[1]
-        built = build(*args, **kw)
-        lower_kw = {"sim": sim}
-        key = (entry, sim, _grid_key(kw), _shape_key(built))
-        rebuild = build
+        params = _param_key(kw) if entry == "search" else _grid_key(kw)
+    key = (entry, sim, params, _shape_key(built))
+    rebuild = lambda *a, **k: build(*a, **k)[0]
 
     hit = _AOT.get(key)
     if hit is not None:
@@ -356,15 +318,9 @@ def warmup(sim, *, trace: Optional[dict] = None, n_intervals: int = 16,
             out = S.sweep_topology(trace, sim, **g)
         elif entry == "session_tick":
             states = S.init_session_states(sim, 1)
-            ext = np.asarray(trace["ext_load"], np.float32)[None]
-            batch = {"ext_load": ext,
-                     "mem_load": np.asarray(
-                         trace["mem_load"], np.float32)[None],
-                     "int_load": np.asarray(
-                         trace["int_load"], np.float32)[None],
-                     "ext_frac": np.asarray(
-                         [trace["ext_frac"]], np.float32),
-                     "t_mask": np.ones(ext.shape[:2], np.float32)}
+            batch = {k: np.asarray(trace[k], np.float32)[None] for k in
+                     ("ext_load", "mem_load", "int_load", "ext_frac")}
+            batch["t_mask"] = np.ones(batch["mem_load"].shape, np.float32)
             out = S.session_tick(states, batch,
                                  S.selection_tables_jax(sim.cfg), sim)
         elif entry == "search":

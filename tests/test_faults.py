@@ -14,6 +14,7 @@ Pinned here, mirroring the PR 2/4 masking invariants:
     oracle and degrades to the static path bit-for-bit.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro.core import faults, traffic
+from repro.core import simulator as S
 from repro.core.simulator import (Arch, SimConfig, SimSession, engine_stats,
                                   reset_engine_stats, simulate,
                                   simulate_batch, stack_traces, sweep_faults)
@@ -262,6 +264,91 @@ def test_faults_reject_padded_topology_paths():
                               faults.no_faults(sim.cfg, T))
     with pytest.raises(ValueError, match="topology"):
         sweep_topology(tr, sim, n_chiplets=[4])
+
+
+# ---------------------------------------------------------------------------
+# One jitted program per entry point: the fault frame is an optional pytree
+# ---------------------------------------------------------------------------
+
+def _clean_and_faulted_calls(entry, sim):
+    """(jitted function, clean call, faulted call) of one entry point; the
+    faulted call carries a frame that never fires."""
+    tr = _trace(12)
+    frame = faults.compile_faults(
+        [faults.GatewayFault(start=T, chiplet=0, slot=0)], sim.cfg, T)
+    hurt = faults.attach_faults(tr, frame)
+    if entry == "simulate":
+        return (S._simulate_jit, lambda: simulate(tr, sim),
+                lambda: simulate(hurt, sim))
+    if entry == "simulate_batch":
+        return (S._sweep_workload_jit, lambda: simulate_batch([tr, tr], sim),
+                lambda: simulate_batch([hurt, hurt], sim))
+    if entry == "sweep_faults":
+        lm = [0.01, 0.02]
+        return (S._sweep_jit, lambda: S.sweep(tr, sim, l_m=lm),
+                lambda: sweep_faults(tr, sim, [frame, frame], l_m=lm))
+    if entry == "step_chunk":
+        session = SimSession.init(sim)
+        clean = list(traffic.chunk_trace(tr, 6))
+        hurt_chunks = list(traffic.chunk_trace(hurt, 6))
+        return (S._session_chunk_jit,
+                lambda: session.step_chunk(clean[0]),
+                lambda: session.step_chunk(hurt_chunks[1]))
+    states = S.init_session_states(sim, 2)
+    batch = stack_traces([tr, tr], pad=True)
+    tables = S.selection_tables_jax(sim.cfg)
+    return (S._session_tick_jit,
+            lambda: S.session_tick(states, batch, tables, sim),
+            lambda: S.session_tick(states, batch, tables, sim, frame=frame))
+
+
+@pytest.mark.parametrize("entry", ["simulate", "simulate_batch",
+                                   "sweep_faults", "step_chunk",
+                                   "session_tick"])
+def test_entry_point_is_one_program_clean_and_faulted(entry):
+    """A clean call and a faulted call are two cache entries of ONE jitted
+    function (two traces); repeating either traces nothing."""
+    sim = SimConfig()
+    jit_fn, clean, hurt = _clean_and_faulted_calls(entry, sim)
+    S.clear_engine_caches()
+    reset_engine_stats()
+    jax.block_until_ready((clean(), hurt()))
+    assert engine_stats()["simulate_traces"] == 2, engine_stats()
+    assert jit_fn._cache_size() == 2
+    reset_engine_stats()
+    jax.block_until_ready((clean(), hurt()))
+    assert engine_stats()["simulate_traces"] == 0, engine_stats()
+    assert jit_fn._cache_size() == 2
+
+
+@pytest.mark.parametrize("name", ["_sweep_batch_jit",
+                                  "_sweep_topology_batch_jit",
+                                  "_session_tick_jit"])
+def test_benchmark_entry_program_names(name, monkeypatch):
+    """perfbench finds the cells' entry programs in device traces by name
+    (`jit_<name>`): the public entry point must dispatch a program of that
+    name."""
+    jit_fn = getattr(S, name)
+    modules = []
+
+    def spy(*args, **kw):
+        text = jit_fn.lower(*args, **kw).as_text()
+        modules.append(re.search(r"module @(\S+)", text).group(1))
+        return jit_fn(*args, **kw)
+
+    monkeypatch.setattr(S, name, spy)
+    sim = SimConfig()
+    tr = _trace(13)
+    if name == "_sweep_batch_jit":
+        S.sweep_batch([tr, tr], sim, l_m=[0.01, 0.02])
+    elif name == "_sweep_topology_batch_jit":
+        S.sweep_topology_batch([tr, tr], sim, n_chiplets=[4, 4],
+                               gateways_per_chiplet=[4, 2])
+    else:
+        S.session_tick(S.init_session_states(sim, 2),
+                       stack_traces([tr, tr], pad=True),
+                       S.selection_tables_jax(sim.cfg), sim)
+    assert modules == [f"jit_{name}"]
 
 
 # ---------------------------------------------------------------------------

@@ -115,6 +115,37 @@ def test_aot_session_tick_matches_jit():
                        S.session_tick(states, batch, tables, sim))
 
 
+@pytest.mark.parametrize("entry", ["simulate", "session_tick"])
+def test_aot_faulted_entry_matches_jit(entry):
+    """The AOT operands are the entry point's own, fault frame included:
+    the compiled call runs the faulted program, not the clean one."""
+    from repro.core import faults
+
+    sim = _sim()
+    tr = _trace(sim)
+    frame = faults.compile_faults(
+        [faults.GatewayFault(start=2, chiplet=0, slot=0),
+         faults.LossDrift(start=3, db_per_interval=0.5)], sim.cfg, 8)
+    if entry == "simulate":
+        args, kw = (faults.attach_faults(tr, frame), sim), {}
+    else:
+        args = (S.init_session_states(sim, 2),
+                S.stack_traces([tr, tr], pad=True),
+                S.selection_tables_jax(sim.cfg), sim)
+        kw = {"frame": frame}
+    got = rcache.aot_compile(entry, *args, **kw)(*args, **kw)
+    _assert_tree_equal(got, getattr(S, entry)(*args, **kw))
+    # The frame bites: the clean program reads another power.
+    if entry == "simulate":
+        clean = S.simulate(tr, sim)
+        assert float(got["summary"]["mean_power_mw"]) \
+            != float(clean["summary"]["mean_power_mw"])
+    else:
+        clean = S.session_tick(*args)
+        assert not np.array_equal(np.asarray(got[2]["power_mw"]),
+                                  np.asarray(clean[2]["power_mw"]))
+
+
 def test_aot_search_matches_jit():
     from repro.core import pareto
 

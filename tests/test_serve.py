@@ -571,8 +571,8 @@ def test_fault_storm_heals_lanes_without_dropping_sessions():
                  if e.get("healed") is None and e["tick"] >
                  next(ev["tick"] for ev in server.events if ev.get("healed"))]
     assert any(not e["breach"] for e in post_heal)
-    # Two executables max (clean tick + fault-twin tick), zero recompiles
-    # from the swap.
+    # Two executables max (the tick's clean and faulted programs), zero
+    # recompiles from the swap.
     assert engine_stats()["simulate_traces"] <= 2, engine_stats()
     # And the storm-crossing sessions still bit-match their replay (same
     # shared frames, same placements, same order).
