@@ -91,6 +91,7 @@ from repro.core.selection import (N_DEFAULT_EDGE_SLOTS,
                                   padded_selection_tables_jax,
                                   resolve_gateway_positions,
                                   selection_tables_jax)
+from repro.core.traffic.transform import _as_array, _xp
 from repro.runtime import spans
 
 
@@ -826,24 +827,26 @@ def _chunk_impl(state: SimState, ext, mem, intra, ext_frac, t_mask,
 def _trace_arrays(trace: dict) -> Tuple[jax.Array, ...]:
     """(ext, mem, intra, ext_frac, t_mask, dest) — dest is None (an empty
     jit/vmap pytree, so destination-free traces keep their exact executable
-    signatures) unless the trace carries a destination matrix."""
+    signatures) unless the trace carries a destination matrix. Each array
+    keeps its kind (`transform._as_array`): a host batch (`stack_traces`)
+    reaches the jitted entry as its arguments, with no eager device op."""
     traffic.validate_trace(trace)
     mem = trace["mem_load"]
     t_mask = trace.get("t_mask")
-    t_mask = jnp.ones(jnp.shape(mem), jnp.float32) if t_mask is None \
-        else jnp.asarray(t_mask, jnp.float32)
+    t_mask = _xp(mem).ones(jnp.shape(mem), np.float32) if t_mask is None \
+        else _as_array(t_mask, np.float32)
     dest = trace.get("dest")
-    dest = None if dest is None else jnp.asarray(dest, jnp.float32)
+    dest = None if dest is None else _as_array(dest, np.float32)
     return (trace["ext_load"], mem, trace["int_load"],
-            jnp.asarray(trace["ext_frac"]), t_mask, dest)
+            _as_array(trace["ext_frac"]), t_mask, dest)
 
 
 def _fault_xs(frame: dict, n_intervals: int,
               what: str) -> Tuple[jax.Array, ...]:
     """A fault frame (`faults.compile_faults`, or K of them stacked) as
     scan xs: (gw_ok [..., T, C, G], stuck_on [..., T, C, G], drift_db
-    [..., T]), float32. Raises when a key is missing or T is not
-    `n_intervals`, the length of `what`."""
+    [..., T]), float32, each of the frame's array kind. Raises when a key
+    is missing or T is not `n_intervals`, the length of `what`."""
     missing = [k for k in FAULT_KEYS if k not in frame]
     if missing:
         present = [k for k in FAULT_KEYS if k in frame]
@@ -851,7 +854,7 @@ def _fault_xs(frame: dict, n_intervals: int,
             f"the fault frame of {what} has {present} but is missing "
             f"{missing} — build a complete one with faults.compile_faults "
             f"or faults.no_faults (faults.attach_faults on a trace)")
-    flt = tuple(jnp.asarray(frame[k], jnp.float32) for k in FAULT_KEYS)
+    flt = tuple(_as_array(frame[k], np.float32) for k in FAULT_KEYS)
     t = int(flt[0].shape[-3])
     if t != n_intervals:
         raise ValueError(
@@ -1025,6 +1028,15 @@ def rebuild_selection_tables(cfg: NetworkConfig) -> dict:
 SelectionTables_rebuild = rebuild_selection_tables
 
 
+def _stack_host(parts: list) -> np.ndarray:
+    """`jnp.stack` of host values, on the host: each part canonicalized
+    as `jnp.asarray` would, then cast to the dtype `jnp.stack` promotes
+    them to."""
+    parts = [_as_array(np.asarray(p)) for p in parts]
+    dtype = jnp.result_type(*parts)
+    return np.stack([p.astype(dtype, copy=False) for p in parts])
+
+
 @spans.root("sim.stack_traces")
 def stack_traces(traces: List[dict], *, pad: bool = False) -> dict:
     """Stack N traces along a new leading batch axis.
@@ -1036,9 +1048,20 @@ def stack_traces(traces: List[dict], *, pad: bool = False) -> dict:
     reduction, so padded lane k simulates identically to the unpadded
     trace k. Without `pad=True`, ragged inputs raise a ValueError naming
     the lengths (instead of the old cryptic jnp stacking error).
+
+    Concrete traces (no leaf a tracer) stack on the host: one
+    `jax.device_get` brings every trace across (span `sim.fetch_traces`),
+    validation and padding read those copies, and the batch comes out as
+    numpy arrays, in the dtypes `jnp.stack` would give. Under `jit` or
+    `vmap` the traces stack through `jax.numpy`.
     """
     if not traces:
         raise ValueError("stack_traces() needs at least one trace")
+    host = not any(isinstance(x, jax.core.Tracer)
+                   for x in jax.tree_util.tree_leaves(traces))
+    if host:
+        with spans.span("sim.fetch_traces"):
+            traces = jax.device_get(list(traces))
     for i, tr in enumerate(traces):
         traffic.validate_trace(tr, who=f"traces[{i}]")
     chips = sorted({int(jnp.shape(tr["ext_load"])[-1]) for tr in traces})
@@ -1074,8 +1097,9 @@ def stack_traces(traces: List[dict], *, pad: bool = False) -> dict:
         + (("t_mask",) if masked else ()) \
         + (("dest",) if n_dest else ()) \
         + (FAULT_KEYS if n_faulted else ())
-    out = {k: jnp.stack([jnp.asarray(tr[k]) for tr in traces])
-           for k in keys}
+    stack = _stack_host if host \
+        else (lambda parts: jnp.stack([jnp.asarray(p) for p in parts]))
+    out = {k: stack([tr[k] for tr in traces]) for k in keys}
     out["app"] = [tr.get("app", "?") for tr in traces]
     return out
 

@@ -35,6 +35,7 @@ SPANS = (
     "repro.traffic.generate",    # traffic.generate
     "repro.traffic.validate",    # traffic.validate_trace on concrete arrays
     "repro.sim.stack_traces",    # simulator.stack_traces
+    "repro.sim.fetch_traces",    #   one device_get of concrete traces
     "repro.sim.sweep_batch",     # simulator.sweep_batch
     "repro.sim.sweep_topology_batch",   # simulator.sweep_topology_batch
     "repro.sim.dispatch",        #   the entry's jitted call

@@ -45,10 +45,16 @@ def traced(tmp_path_factory):
         server.submit(SessionRequest(
             trace=traffic.generate(specs[0], keys[i], sim.cfg)))
 
+    def stack_under_jit(trs):
+        out = simulator.stack_traces(trs)
+        return {k: v for k, v in out.items() if k != "app"}
+
     for i in range(2):              # compile everything the trace runs
         submit(i)
     server.tick()
     sweep(0)
+    arrays = [{k: v for k, v in traffic.generate(s, keys[j], sim.cfg).items()
+               if k != "app"} for j, s in enumerate(specs)]
     d = tmp_path_factory.mktemp("spans_trace")
     with jax.profiler.trace(str(d)):
         with jax.profiler.TraceAnnotation("bench.window"):
@@ -58,6 +64,7 @@ def traced(tmp_path_factory):
                 with jax.profiler.TraceAnnotation("bench.tick"):
                     server.tick()
             sweep(2)
+            jax.block_until_ready(jax.jit(stack_under_jit)(arrays))
     path = Path(sorted(glob.glob(str(d / "**" / "*.xplane.pb"),
                                  recursive=True))[-1])
     return {"reduced": span_reduce.reduce_file(path, "_session_tick_jit"),
@@ -113,7 +120,23 @@ def test_child_spans_carry_their_roots_id(traced):
     calls = [e[3]["call"] for e in ev if e[0] in
              ("repro.traffic.generate", "repro.sim.stack_traces",
               "repro.sim.sweep_batch")]
-    assert len(calls) == len(set(calls)) == len(APPS) + 4
+    # The sweep's generates, its stack and entry, two submits' generates,
+    # and the stack traced under jit.
+    assert len(calls) == len(set(calls)) == len(APPS) + 5
+
+
+def test_one_fetch_per_stack_of_concrete_traces_none_under_jit(traced):
+    ev = traced["events"]
+    stacks = sorted((e for e in ev if e[0] == "repro.sim.stack_traces"),
+                    key=lambda e: e[1])
+    fetches = [e for e in ev if e[0] == "repro.sim.fetch_traces"]
+    # The sweep's concrete traces fetch once; the jitted stack, none.
+    assert [sum(s[1] <= f[1] and f[2] <= s[2] for f in fetches)
+            for s in stacks] == [1, 0]
+    assert len(fetches) == 1
+    assert fetches[0][3] == stacks[0][3]
+    assert traced["reduced"]["spans"]["repro.sim.fetch_traces"]["count"] \
+        == 1
 
 
 def test_self_time_never_exceeds_total(traced):

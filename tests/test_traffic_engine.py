@@ -18,13 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import traffic
+from repro.core import faults, traffic
 from repro.core.constants import NETWORK
 from repro.core.simulator import (Arch, SimConfig, SimSession, engine_stats,
                                   reset_engine_stats, simulate,
                                   simulate_batch, simulate_stream,
-                                  stack_traces, sweep_topology,
-                                  sweep_workload, topology_point_config)
+                                  stack_traces, sweep_batch,
+                                  sweep_topology, sweep_workload,
+                                  topology_point_config)
 from repro.kernels.noc_step.kernel import noc_run_pallas
 from repro.kernels.noc_step.ops import build_topology
 from repro.kernels.noc_step.ref import reference_noc_run
@@ -103,6 +104,104 @@ def test_stack_traces_error_paths(ragged_traces):
     batch = stack_traces(ragged_traces, pad=True)
     assert batch["ext_load"].shape == (3, 21, NETWORK.n_chiplets)
     assert batch["t_mask"].shape == (3, 21)
+
+
+def _stacking_inputs(case):
+    """(traces, pad) for one `stack_traces` parity case."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    lengths = (12, 7, 10) if case == "ragged" else (12, 12, 12)
+    traces = [traffic.generate(traffic.ParsecSpec(app=a, n_intervals=t), k,
+                               dest=case == "dest")
+              for a, t, k in zip(("dedup", "canneal", "facesim"), lengths,
+                                 keys)]
+    if case == "host":
+        traces = jax.device_get(traces)
+    elif case == "host_float64":
+        traces = [{k: v if k == "app" else np.asarray(v, np.float64)
+                   for k, v in tr.items()} for tr in traces]
+    elif case == "mixed":
+        traces[1] = jax.device_get(traces[1])
+    elif case == "faults":
+        traces = [faults.attach_faults(tr, faults.compile_faults(
+            [faults.GatewayFault(start=i, chiplet=i, slot=0)], NETWORK, 12))
+            for i, tr in enumerate(traces)]
+    return traces, case == "ragged"
+
+
+def _jnp_stacked(traces, pad):
+    """`stack_traces`'s batch as `jnp.stack` builds it on the device."""
+    t = max(int(jnp.shape(tr["ext_load"])[0]) for tr in traces)
+    traces = [traffic.pad_trace(tr, t) for tr in traces] if pad else traces
+    keys = [k for k in traces[0] if k != "app"]
+    return {k: jnp.stack([jnp.asarray(tr[k]) for tr in traces])
+            for k in keys}
+
+
+@pytest.mark.parametrize("case", ["device", "host", "host_float64",
+                                  "mixed", "ragged", "dest", "faults"])
+def test_stack_traces_host_build_bit_matches_jnp_stack(case):
+    """Concrete traces stack on the host: numpy arrays whose bytes and
+    dtypes equal `jnp.stack` on the device."""
+    traces, pad = _stacking_inputs(case)
+    out = stack_traces(traces, pad=pad)
+    ref = _jnp_stacked(traces, pad)
+    assert set(out) - {"app"} == set(ref)
+    assert out["app"] == ["dedup", "canneal", "facesim"]
+    for k, want in ref.items():
+        assert isinstance(out[k], np.ndarray), k
+        assert out[k].dtype == want.dtype, k
+        assert out[k].shape == want.shape, k
+        assert out[k].tobytes() == np.asarray(want).tobytes(), k
+
+
+def test_stack_traces_under_jit_stacks_tracers():
+    traces, _ = _stacking_inputs("ragged")
+    arrays = [{k: v for k, v in tr.items() if k != "app"} for tr in traces]
+
+    def stacked(trs):
+        out = stack_traces(trs, pad=True)
+        assert isinstance(out["ext_load"], jax.core.Tracer)
+        return {k: v for k, v in out.items() if k != "app"}
+
+    out = jax.jit(stacked)(arrays)
+    ref = _jnp_stacked(traces, True)
+    assert set(out) == set(ref)
+    for k, want in ref.items():
+        assert isinstance(out[k], jax.Array), k
+        assert out[k].dtype == want.dtype, k
+        assert np.asarray(out[k]).tobytes() == np.asarray(want).tobytes(), k
+
+
+def test_sweep_batch_over_host_built_batch_bit_matches_device_stack():
+    sim = SimConfig()
+    keys = jax.random.split(jax.random.PRNGKey(5), 2)
+    traces = [traffic.generate(traffic.ParsecSpec(app=a, n_intervals=16), k)
+              for a, k in zip(("blackscholes", "bodytrack"), keys)]
+    grid = dict(l_m=np.asarray([0.008, 0.008, 0.02, 0.02], np.float32),
+                wavelengths=np.asarray([2, 8, 2, 8], np.int32))
+    got = sweep_batch(traces, sim, **grid)
+    want = sweep_batch(_jnp_stacked(traces, True), sim, **grid)
+    for part in ("summary", "records"):
+        for k, v in want[part].items():
+            assert np.asarray(got[part][k]).tobytes() \
+                == np.asarray(v).tobytes(), f"{part}[{k}]"
+
+
+@pytest.mark.parametrize("kind", ["device", "host"])
+@pytest.mark.parametrize("bad", [np.nan, -1.0], ids=["nan", "negative"])
+def test_stack_traces_still_validates_every_value(kind, bad):
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    traces = [traffic.generate(traffic.ParsecSpec(app="dedup",
+                                                  n_intervals=6), k)
+              for k in keys]
+    if kind == "host":
+        traces = jax.device_get(traces)
+    load = np.array(traces[1]["int_load"])
+    load[3, 1] = bad
+    traces[1] = dict(traces[1], int_load=(
+        load if kind == "host" else jnp.asarray(load)))
+    with pytest.raises(ValueError, match=r"traces\[1\]\['int_load'\]"):
+        stack_traces(traces)
 
 
 def test_padded_single_trace_through_simulate():
